@@ -72,13 +72,12 @@ def _measure_cell(C, K, D, F, seed=0):
     import jax.numpy as jnp
 
     from benchmarks.speed import _timeit, _synthetic_full_ubm
-    from repro.analysis.roofline import (CPU_HW, HW, align_cost_model,
-                                         autotune_align)
+    from repro.analysis.roofline import (align_cost_model, autotune_align,
+                                         local_hardware)
     from repro.core import ubm as U
     from repro.kernels import ops
 
-    backend = jax.default_backend()
-    hw = CPU_HW if backend == "cpu" else HW
+    hw = local_hardware()
     key = jax.random.PRNGKey(seed)
     ubm = _synthetic_full_ubm(key, C, D)
     pre = U.full_precisions(ubm)
@@ -87,7 +86,7 @@ def _measure_cell(C, K, D, F, seed=0):
     diag_ll = U.diag_loglik(ubm.to_diag(), x)
     sel = jax.lax.top_k(diag_ll, K)[1].astype(jnp.int32)
 
-    tune = autotune_align(C, K, D, backend=backend, frames=F)
+    tune = autotune_align(C, K, D, device_kind=hw.name, frames=F)
     seen, cands = set(), []
     for strategy, bf, depth, _t in tune.candidates:
         if (strategy, bf) in seen:
@@ -107,7 +106,8 @@ def _measure_cell(C, K, D, F, seed=0):
     winner = next(c for c in cands if c["strategy"] == tune.strategy
                   and c["block_f"] == tune.block_f)
     return {
-        "cell": {"C": C, "K": K, "D": D, "frames": F, "backend": backend},
+        "cell": {"C": C, "K": K, "D": D, "frames": F,
+                 "device_kind": hw.name},
         "candidates": cands,
         "predicted_winner": {"strategy": tune.strategy,
                              "block_f": int(tune.block_f),
@@ -121,16 +121,16 @@ def _measure_cell(C, K, D, F, seed=0):
     }
 
 
-def _model_cell(C, K, D, backend="tpu", frames=4096):
+def _model_cell(C, K, D, device_kind="TPU v5 lite", frames=4096):
     """Model-only cell (no such accelerator here): the full candidate
     sweep with predictions, recording where the union/full crossover sits
     at paper scale."""
     from repro.analysis.roofline import autotune_align
 
-    tune = autotune_align(C, K, D, backend=backend, frames=frames)
+    tune = autotune_align(C, K, D, device_kind=device_kind, frames=frames)
     return {
         "cell": {"C": C, "K": K, "D": D, "frames": frames,
-                 "backend": backend, "model_only": True},
+                 "device_kind": device_kind, "model_only": True},
         "candidates": [
             {"strategy": s, "block_f": int(bf), "dma_depth": int(dp),
              "t_predicted": t}

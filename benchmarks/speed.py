@@ -245,7 +245,7 @@ def paper_scale_flops(C=2048, D=60, K=20, F=4096):
     pre = (sd((C,), f32_), sd((C, D), f32_), sd((C, D, D), f32_))
     apack = sd((C, E2), f32_)
     x = sd((F, D), f32_)
-    tune = autotune_align(C, K, D, backend="tpu")
+    tune = autotune_align(C, K, D, device_kind="TPU v5 lite")
     out = {"config": {"n_components": C, "feat_dim": D, "top_k": K,
                       "frames": F, "compile_only": True},
            "tpu_autotune": {"strategy": tune.strategy,
@@ -786,7 +786,6 @@ def _stream_worker(spec):
     import subprocess
     env = dict(os.environ)
     env["PYTHONPATH"] = f"{REPO_ROOT / 'src'}:{REPO_ROOT}"
-    env.setdefault("JAX_PLATFORMS", "cpu")
     out = subprocess.run(
         [sys.executable, "-c", _STREAM_WORKER, json.dumps(spec)],
         capture_output=True, text=True, env=env, timeout=900)
@@ -862,7 +861,11 @@ def streaming_compare(C=64, D=12, R=32, n_sessions=12, n_rounds=6,
     resilience guardrail); p50/p99 queue latency under a synchronized
     burst through the adaptive admission queue; a hot-swap + rollback
     under interleaved traffic (failed requests must be 0, rollback
-    bit-exact); and the subprocess kill -9 chaos drill."""
+    bit-exact); and the subprocess kill -9 chaos drill.
+
+    The drill runs first: its children each need the device, and a
+    parent that has started a JAX backend holds it (one process per
+    chip)."""
     import tempfile
 
     from repro.api.bundle import Bundle
@@ -873,6 +876,9 @@ def streaming_compare(C=64, D=12, R=32, n_sessions=12, n_rounds=6,
 
     overrides = dict(feat_dim=D, n_components=C, ivector_dim=R,
                      posterior_top_k=min(8, C), frames_per_utt=chunk_frames)
+    chaos = streaming_chaos_drill(
+        overrides, n_sessions=n_sessions, n_rounds=n_rounds,
+        chunk_frames=chunk_frames, seed=seed)
     cfg = SMOKE.with_overrides(**overrides)
     key = jax.random.PRNGKey(seed)
     ubm = _synthetic_full_ubm(key, C, D)
@@ -1020,11 +1026,7 @@ def streaming_compare(C=64, D=12, R=32, n_sessions=12, n_rounds=6,
                 quiet_iv, store.solve("s0"))),
             "draining_after_rollback": store.draining(),
         }
-
-    # -- the kill -9 drill (subprocesses) ----------------------------------
-    out["chaos"] = streaming_chaos_drill(
-        overrides, n_sessions=n_sessions, n_rounds=n_rounds,
-        chunk_frames=chunk_frames, seed=seed)
+    out["chaos"] = chaos
     return out
 
 

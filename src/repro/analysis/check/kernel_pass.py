@@ -28,10 +28,10 @@ from typing import List, Optional
 from repro.analysis import roofline
 from repro.analysis.check.findings import Finding, make_finding
 
-# _RESIDENT_BYTES is the *streaming* working-set target (half VMEM, so
+# resident_bytes is the *streaming* working-set target (half VMEM, so
 # the pipeline can double-buffer); a kernel instance may legally fill
 # the whole core => budget is 2x.
-VMEM_BUDGET_BYTES = 2 * roofline._RESIDENT_BYTES["tpu-v5e"]
+VMEM_BUDGET_BYTES = 2 * roofline.hardware("TPU v5 lite").resident_bytes
 
 
 def _check_divisibility(spec, inst) -> List[Finding]:

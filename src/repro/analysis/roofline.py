@@ -22,20 +22,62 @@ from typing import Dict, Optional
 from repro.analysis import optable
 
 # ---------------------------------------------------------------------------
-# Hardware constants (TPU v5e)
+# Hardware table, keyed by jax's ``device_kind``
 # ---------------------------------------------------------------------------
 
 
 @dataclass(frozen=True)
 class Hardware:
-    name: str = "tpu-v5e"
-    peak_flops: float = 197e12      # bf16 FLOP/s per chip
-    hbm_bw: float = 819e9           # B/s per chip
-    link_bw: float = 50e9           # B/s per ICI link
-    hbm_bytes: float = 16e9         # per-chip capacity
+    name: str                # jax ``device_kind``
+    peak_flops: float        # bf16 FLOP/s per chip
+    hbm_bw: float            # B/s per chip
+    link_bw: float           # B/s per ICI link
+    hbm_bytes: float         # per-chip capacity
+    # fused-alignment cost-model constants (DESIGN.md §12), unmeasured:
+    gather_bw: float         # effective B/s of data-dependent row gathers
+    dma_issue_s: float       # exposed per-DMA issue overhead
+    gather_overlap: bool     # row gathers hide under the rescore GEMM
+    resident_bytes: float    # on-chip budget for a resident [C, E2] pack
 
 
-HW = Hardware()
+HARDWARE: Dict[str, Hardware] = {h.name: h for h in (
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM
+    # at 819 GB/s, 1,600 Gbit/s of ICI (4 links, 50 GB/s each). The TPU
+    # kernel's sorted row DMAs run near HBM bandwidth and its DMA ring
+    # prefetches the next tile's rows under the current tile's matmul;
+    # the resident budget is half of the 16 MB scoped VMEM.
+    Hardware(name="TPU v5 lite", peak_flops=197e12, hbm_bw=819e9,
+             link_bw=50e9, hbm_bytes=16e9, gather_bw=600e9,
+             dma_issue_s=10e-9, gather_overlap=True, resident_bytes=8e6),
+    # single-core container numbers (measured GEMM throughput ~8e10
+    # FLOP/s f32; streaming ~2e10 B/s). Row gathers on the CPU jnp path
+    # materialise through scalar copy loops (~1.5 GB/s) and run before
+    # the GEMM, not under it — what flips the union/full crossover.
+    Hardware(name="cpu", peak_flops=8e10, hbm_bw=2e10, link_bw=1e9,
+             hbm_bytes=4e9, gather_bw=1.5e9, dma_issue_s=0.0,
+             gather_overlap=False, resident_bytes=2e6),
+)}
+
+
+def hardware(device_kind: str) -> Hardware:
+    """The table entry for ``device_kind``; an unknown device is an
+    error, never a default."""
+    try:
+        return HARDWARE[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no roofline entry for device_kind {device_kind!r}; "
+            f"known: {sorted(HARDWARE)}") from None
+
+
+def local_hardware() -> Hardware:
+    """The table entry for this process's first device."""
+    import jax
+    return hardware(jax.devices()[0].device_kind)
+
+
+# the chip the dry-run roofline reports are computed for
+HW = hardware("TPU v5 lite")
 
 # shared op-table (DESIGN.md §15): this module used to carry its own
 # dtype/shape/collective copies and had already drifted from hlo_cost's
@@ -88,38 +130,13 @@ def collective_bytes_from_hlo(hlo_text: str) -> Dict[str, int]:
 # Fused-alignment block-size autotuner (DESIGN.md §12)
 # ---------------------------------------------------------------------------
 
-# CPU profile for the same cost model: single-core container numbers
-# (measured GEMM throughput ~8e10 FLOP/s f32; streaming ~2e10 B/s). The
-# load-bearing difference from the TPU profile is gather_bw: row gathers
-# on the CPU jnp path materialise through scalar copy loops (~1.5 GB/s)
-# while the TPU kernel's sorted row DMAs run near HBM bandwidth — this is
-# what flips the union/full crossover between backends.
-CPU_HW = Hardware(name="cpu", peak_flops=8e10, hbm_bw=2e10, link_bw=1e9,
-                  hbm_bytes=4e9)
-
-# effective bandwidth of data-dependent row gathers per backend
-_GATHER_BW = {"tpu-v5e": 600e9, "cpu": 1.5e9}
-# exposed per-DMA issue overhead (scalar core), amortised by the
-# dma_depth-deep pipeline in the fused kernel
-_DMA_ISSUE_S = {"tpu-v5e": 10e-9, "cpu": 0.0}
-# whether row gathers overlap the rescore GEMM: the TPU kernel's DMA ring
-# prefetches the next tile's rows under the current tile's matmul, so the
-# gather hides under max(); the CPU jnp path runs take() then GEMM
-# sequentially, so its gather time is additive
-_GATHER_OVERLAP = {"tpu-v5e": True, "cpu": False}
-# on-chip budget for keeping the whole [C, E2] pack resident across
-# frame-tiles (half of VMEM on TPU; ~L2 on the CPU backend). Past this
-# the 'full' strategy re-streams the pack per tile — which is exactly
-# when the union gather's C/(BF·K) byte cut starts paying
-_RESIDENT_BYTES = {"tpu-v5e": 8e6, "cpu": 2e6}
-
 _ALIGN_BLOCK_F = (8, 16, 32, 64, 128)
 _ALIGN_DMA_DEPTH = (2, 4, 8)
 
 
 @dataclass(frozen=True)
 class AlignTune:
-    """Winning fused-alignment schedule for one (C, K, D, backend) cell."""
+    """Winning fused-alignment schedule for one (C, K, D, device) cell."""
     strategy: str            # 'union' (tile-union gather-GEMM) | 'full'
     block_f: int             # frame-tile BF
     dma_depth: int           # DMA semaphore ring depth
@@ -142,15 +159,14 @@ def align_cost_model(C: int, K: int, D: int, *, block_f: int,
     E2 = 1 + D + D * (D + 1) // 2
     tiles = -(-frames // block_f)
     xe_bytes = 4.0 * frames * E2
-    gather_bw = _GATHER_BW.get(hw.name, hw.hbm_bw)
+    gather_bw = hw.gather_bw
     if strategy == "union":
         u = min(block_f * K, C)
         flops = 2.0 * frames * u * E2
         gather_bytes = 4.0 * tiles * u * E2
         t_gather = gather_bytes / gather_bw
-        t_issue = tiles * u * _DMA_ISSUE_S.get(hw.name, 0.0) / max(
-            dma_depth, 1)
-        if _GATHER_OVERLAP.get(hw.name, True):
+        t_issue = tiles * u * hw.dma_issue_s / max(dma_depth, 1)
+        if hw.gather_overlap:
             t_mem = t_gather + xe_bytes / hw.hbm_bw
         else:
             # sequential gather-then-GEMM: the gather never hides under
@@ -160,7 +176,7 @@ def align_cost_model(C: int, K: int, D: int, *, block_f: int,
     elif strategy == "full":
         flops = 2.0 * frames * C * E2
         pack_bytes = 4.0 * C * E2
-        if pack_bytes > _RESIDENT_BYTES.get(hw.name, 8e6):
+        if pack_bytes > hw.resident_bytes:
             pack_bytes *= tiles            # re-streamed every frame-tile
         t_mem = (pack_bytes + xe_bytes) / hw.hbm_bw
         t_issue = 0.0
@@ -172,9 +188,11 @@ def align_cost_model(C: int, K: int, D: int, *, block_f: int,
 _ALIGN_TUNE_CACHE: Dict[tuple, "AlignTune"] = {}
 
 
-def autotune_align(C: int, K: int, D: int, *, backend: Optional[str] = None,
+def autotune_align(C: int, K: int, D: int, *,
+                   device_kind: Optional[str] = None,
                    frames: int = 4096) -> AlignTune:
-    """Pick the fused-alignment schedule for one (C, K, D, backend) cell.
+    """Pick the fused-alignment schedule for one (C, K, D, device) cell
+    (``device_kind`` defaults to this process's first device).
 
     Sweeps (strategy, BF, dma_depth) through ``align_cost_model`` and
     caches the winner — the sweep is pure arithmetic, so tuning happens
@@ -182,14 +200,11 @@ def autotune_align(C: int, K: int, D: int, *, backend: Optional[str] = None,
     records predicted-vs-measured for every candidate into
     ``BENCH_autotune.json`` to keep the model honest.
     """
-    if backend is None:
-        import jax
-        backend = jax.default_backend()
-    key = (C, K, D, backend)
+    hw = local_hardware() if device_kind is None else hardware(device_kind)
+    key = (C, K, D, hw.name, frames)
     hit = _ALIGN_TUNE_CACHE.get(key)
     if hit is not None:
         return hit
-    hw = CPU_HW if backend == "cpu" else HW
     rows = []
     # 'full' first: exact ties (u == C makes both strategies pure
     # whole-pack GEMMs FLOP-wise) resolve to the gather-free path

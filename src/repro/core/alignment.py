@@ -15,9 +15,9 @@ two-phase preselect → rescore pipeline:
      'fused'  — packed-GEMM rescoring against the symmetric-packed
                 `align_pack` rows (`kernels.ops.gmm_rescore_fused`, §12):
                 the same C/K cut as 'sparse' with the gather coalesced
-                into tile-level GEMMs — the fast path on every backend
-                (on TPU the whole preselect→top-K→gather→rescore pipeline
-                runs as ONE Pallas kernel, `kernels/gmm_align.py`),
+                into tile-level GEMMs. It is jnp on every backend: the
+                single-kernel `kernels/gmm_align.py` is not on this path
+                (and does not fit VMEM at C=2048),
 3. intersect is free (softmax/floor already operate on the gathered
    [F, K] set, so both modes feed bit-identical downstream math), drop
    posteriors < floor, renormalise to sum 1.

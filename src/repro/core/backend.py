@@ -130,14 +130,30 @@ def _plda_coeffs(plda: PLDA):
     return Q, P, const
 
 
-def plda_score_matrix(plda: PLDA, enroll, test) -> jax.Array:
-    """LLR for every (enroll, test) pair: [N_enroll, N_test]."""
+def _plda_terms(plda: PLDA, enroll, test):
+    """(x Q x-terms [N], y Q y-terms [M], x P [N, R], y [M, R], const).
+
+    The f32 products run at HIGHEST precision: with a near-singular W the
+    quadratic and cross terms are ~1e4 and cancel to an O(1) score, so
+    the TPU's default bf16 rounding of matmul inputs would swamp it."""
     Q, P, const = _plda_coeffs(plda)
     x = enroll - plda.mean
     y = test - plda.mean
-    qx = jnp.sum((x @ Q) * x, axis=1)
-    qy = jnp.sum((y @ Q) * y, axis=1)
-    cross = (x @ P) @ y.T
+    hi = jax.lax.Precision.HIGHEST
+    qx = jnp.sum(jnp.dot(x, Q, precision=hi) * x, axis=1)
+    qy = jnp.sum(jnp.dot(y, Q, precision=hi) * y, axis=1)
+    return qx, qy, jnp.dot(x, P, precision=hi), y, const
+
+
+def plda_score_matrix(plda: PLDA, enroll, test) -> jax.Array:
+    """LLR for every (enroll, test) pair: [N_enroll, N_test].
+
+    The cross term is a broadcast multiply-and-sum, not a matmul, so each
+    entry rounds exactly as ``plda_score_pairs`` does: under the
+    cancellation above, a matmul's different summation order moves the
+    score by ~1e-4 relative."""
+    qx, qy, xP, y, const = _plda_terms(plda, enroll, test)
+    cross = jnp.sum(xP[:, None, :] * y[None, :, :], axis=-1)
     return 0.5 * (qx[:, None] + qy[None, :]) + cross + const
 
 
@@ -147,12 +163,8 @@ def plda_score_pairs(plda: PLDA, enroll, test) -> jax.Array:
     O(N) — trial-list evaluation must not build the full N x N score
     matrix only to read its diagonal.
     """
-    Q, P, const = _plda_coeffs(plda)
-    x = enroll - plda.mean
-    y = test - plda.mean
-    qx = jnp.sum((x @ Q) * x, axis=1)
-    qy = jnp.sum((y @ Q) * y, axis=1)
-    cross = jnp.sum((x @ P) * y, axis=1)
+    qx, qy, xP, y, const = _plda_terms(plda, enroll, test)
+    cross = jnp.sum(xP * y, axis=1)
     return 0.5 * (qx + qy) + cross + const
 
 

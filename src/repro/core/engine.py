@@ -55,6 +55,7 @@ reduction.
 from __future__ import annotations
 
 import dataclasses
+import warnings
 from dataclasses import dataclass
 from typing import NamedTuple, Optional, Sequence, Tuple
 
@@ -66,7 +67,7 @@ from repro.core import alignment as AL
 from repro.core import stats as ST
 from repro.core import tvm as TV
 from repro.core import ubm as U
-from repro.kernels import compat, ops
+from repro.kernels import ops
 
 f32 = jnp.float32
 
@@ -96,6 +97,13 @@ def degrade_rescore(mode: str) -> Optional[str]:
     reference path — a failure there is a real bug, not a kernel issue)."""
     i = RESCORE_LADDER.index(mode)
     return RESCORE_LADDER[i + 1] if i + 1 < len(RESCORE_LADDER) else None
+
+
+def warn_demotion(mode: str, nxt: str, exc: BaseException) -> None:
+    """A demotion is never silent: a kernel the compiler refuses would
+    otherwise be served by the jnp reference with no sign of it."""
+    warnings.warn(f"rescore mode {mode!r} failed with {exc!r}; demoting "
+                  f"to {nxt!r}", RuntimeWarning, stacklevel=3)
 
 
 class UBMPack(NamedTuple):
@@ -255,6 +263,22 @@ def session_stats(spec: EngineSpec, pack: UBMPack, feats, mask=None):
 # ---------------------------------------------------------------------------
 
 
+def pin(x):
+    """Materialise ``x`` as a buffer of its own, so XLA cannot fuse its
+    producer into the ops that read it.
+
+    The 'ordered' exit reduction is bit-exact against the one-device scan
+    (DESIGN.md §11) only if each chunk is computed the same way in both.
+    The trainer pins the E-step precompute: computed in the same program
+    as the chunk scan, XLA would otherwise fuse it into the scan's dots,
+    which on the CPU picks a dot with another summation order than the
+    mesh path, where the shard_map boundary keeps the two apart.
+    `TotalsAccum` pins each chunk's second-order moments: a scatter-add
+    into zeros followed by an add to the scan carry is rewritten into a
+    scatter into the carry, which sums in another order."""
+    return jax.lax.optimization_barrier(x)
+
+
 class TotalsAccum:
     """Global sufficient statistics: Σ_u n, Σ_u f, Σ S, loglik, frames.
 
@@ -281,7 +305,9 @@ class TotalsAccum:
     def update(self, carry, chunk: ChunkStats):
         n, f, S, ll, fr = carry
         if chunk.S is not None:
-            S = S + chunk.S
+            # pinned: XLA would fold a scattered S into the carry and
+            # reorder the sums against the mesh path's per-chunk S
+            S = S + pin(chunk.S)
         return (n + jnp.sum(chunk.n, axis=0), f + jnp.sum(chunk.f, axis=0),
                 S, ll + chunk.loglik, fr + chunk.frames)
 
@@ -496,8 +522,8 @@ def _stream_sharded(spec: EngineSpec, pack: UBMPack, feats, mask,
     out_specs = (tuple(a.mesh_out_specs(M) for a in accums),
                  (P(data_axes, M), P(data_axes, M, None)) if collect_nf
                  else None)
-    fn_sm = compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
+    fn_sm = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False)
     return fn_sm(feats, mask, pack, margs)
 
 

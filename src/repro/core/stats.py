@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.alignment import SparsePosteriors
+from repro.kernels import ops
 
 f32 = jnp.float32
 
@@ -39,7 +40,7 @@ def scatter_accumulate(x, values, indices, utt_ids, n_utts: int, C: int,
     values/indices: [N, K] sparse posteriors; utt_ids: [N] utterance id per
     frame; mask: [N] optional validity. ``second_order``: None | 'diag' |
     'full' selects S as absent, [C, D] (sum gamma x^2) or [C, D*D]
-    (sum gamma vec(x x^T), row-major).
+    (sum gamma vec(x x^T), row-major; ``kernels.ops.second_moments``).
     """
     N, D = x.shape
     K = values.shape[1]
@@ -60,9 +61,8 @@ def scatter_accumulate(x, values, indices, utt_ids, n_utts: int, C: int,
         sw = (values[:, :, None] * (x * x)[:, None, :]).reshape(N * K, D)
         S = jnp.zeros((C, D), f32).at[rows_c].add(sw)
     elif second_order == "full":
-        x2 = (x[:, :, None] * x[:, None, :]).reshape(N, D * D)
-        x2w = (values[:, :, None] * x2[:, None, :]).reshape(N * K, D * D)
-        S = jnp.zeros((C, D * D), f32).at[rows_c].add(x2w)
+        # a grouped contraction where kernels run (kernels/ops.py)
+        S = ops.second_moments(x, values, indices, C)
     return n, f, S
 
 

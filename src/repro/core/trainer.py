@@ -127,11 +127,11 @@ def _finish_iteration(cfg: IVectorConfig, model: TV.TVModel,
         if model.formulation == "standard":
             S_m = ST.center(ST.BWStats(tot.n[None], tot.f[None],
                                        tot.ss), model.means).S
+    diag = {"mean_phi_norm": jnp.linalg.norm(TV.mean_phi(model, acc)),
+            "avg_loglik": tot.loglik / jnp.maximum(tot.frames, 1.0)}
     model = TV.m_step(model, acc, S_m, cfg.update_sigma)
     if cfg.min_divergence:
         model = TV.min_divergence(model, acc)
-    diag = {"mean_phi_norm": jnp.linalg.norm(acc.h / acc.n_utts),
-            "avg_loglik": tot.loglik / jnp.maximum(tot.frames, 1.0)}
     return model, diag
 
 
@@ -151,18 +151,19 @@ def make_em_fn(cfg: IVectorConfig):
         acc = TV.em_accumulate_scan(model, pre, n_, f_,
                                     chunk=cfg.estep_chunk,
                                     estep_dtype=cfg.estep_dtype)
+        diag = {"mean_phi_norm": jnp.linalg.norm(TV.mean_phi(model, acc))}
         model = TV.m_step(model, acc, S_ if cfg.update_sigma else None,
                           cfg.update_sigma)
         if cfg.min_divergence:
             model = TV.min_divergence(model, acc)
-        return model, {"mean_phi_norm": jnp.linalg.norm(acc.h / acc.n_utts)}
+        return model, diag
 
     return jax.jit(em_iter)
 
 
 def _iter_accums(cfg: IVectorConfig, spec: EN.EngineSpec,
                  model: TV.TVModel, feat_dim: int):
-    pre = TV.precompute(model, estep=cfg.estep)
+    pre = EN.pin(TV.precompute(model, estep=cfg.estep))
     center = model.means if model.formulation == "standard" else None
     return (EN.TotalsAccum(spec, feat_dim),
             EN.TVMAccum(model, pre, center_means=center,

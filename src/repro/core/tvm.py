@@ -20,7 +20,11 @@ from repro.core.stats import BWStats
 from repro.kernels import ops
 
 f32 = jnp.float32
+# f32 contractions run at HIGHEST precision: a TPU's default f32 matmul
+# rounds its inputs to bf16, and the E-step and Σ update cancel large terms
+HI = jax.lax.Precision.HIGHEST
 COV_FLOOR = 1e-4
+VAR_FLOOR_FACTOR = 0.1   # Kaldi's --variance-floor-factor
 
 
 @dataclass
@@ -85,7 +89,7 @@ def precompute(model: TVModel, estep: str = "dense") -> Precomp:
         raise ValueError(f"estep must be 'dense'|'packed', got {estep!r}")
     chol = jnp.linalg.cholesky(model.Sigma)
     Pj = jax.scipy.linalg.cho_solve((chol, True), model.T)
-    Uc = jnp.einsum("cdr,cds->crs", model.T, Pj)
+    Uc = jnp.einsum("cdr,cds->crs", model.T, Pj, precision=HI)
     # exact symmetry before packing (fp round-off from the solve)
     Uc = 0.5 * (Uc + Uc.transpose(0, 2, 1))
     if estep == "packed":
@@ -124,12 +128,12 @@ def posterior(model: TVModel, pre: Precomp, n, f, mean_only: bool = False,
     else:
         # f32 accumulation pinned explicitly (rule NUM001): n may arrive
         # bf16 under the mixed-precision E-step
-        Ld = jnp.einsum("uc,crs->urs", n, pre.U,
+        Ld = jnp.einsum("uc,crs->urs", n, pre.U, precision=HI,
                         preferred_element_type=f32)
         if axis is not None:
             Ld = jax.lax.psum(Ld, axis)
         L = jnp.eye(R, dtype=f32) + Ld
-    rhs = jnp.einsum("cdr,ucd->ur", pre.Pj, f,
+    rhs = jnp.einsum("cdr,ucd->ur", pre.Pj, f, precision=HI,
                      preferred_element_type=f32)
     if axis is not None:
         rhs = jax.lax.psum(rhs, axis)
@@ -148,14 +152,14 @@ def posterior(model: TVModel, pre: Precomp, n, f, mean_only: bool = False,
         if mean_only:
             # two triangular mat-vecs: phi = G^{-T} (G^{-1} rhs); Phi is
             # never materialised at all
-            y = jnp.einsum("urs,us->ur", Gi, rhs,
+            y = jnp.einsum("urs,us->ur", Gi, rhs, precision=HI,
                            preferred_element_type=f32)
-            phi = jnp.einsum("usr,us->ur", Gi, y,
+            phi = jnp.einsum("usr,us->ur", Gi, y, precision=HI,
                              preferred_element_type=f32)
             return phi.astype(f32), None
-        Phi = jnp.einsum("uir,uis->urs", Gi, Gi,
+        Phi = jnp.einsum("uir,uis->urs", Gi, Gi, precision=HI,
                          preferred_element_type=f32)
-        phi = jnp.einsum("urs,us->ur", Phi, rhs,
+        phi = jnp.einsum("urs,us->ur", Phi, rhs, precision=HI,
                          preferred_element_type=f32)
         return phi.astype(f32), Phi.astype(f32)
     phi = jax.scipy.linalg.cho_solve((chol, True), rhs[..., None])[..., 0]
@@ -171,8 +175,11 @@ class EMAccum(NamedTuple):
     A: jax.Array        # [C, R, R]  Σ_u n_uc (Phi_u + phi phi^T);
     #                     packed mode: [C, P] upper triangle
     B: jax.Array        # [C, D, R]  Σ_u f_uc ⊗ phi_u
-    h: jax.Array        # [R]        Σ_u phi_u
-    H: jax.Array        # [R, R]     Σ_u (Phi_u + phi phi^T)
+    # h and H are taken about the model's prior (phi_u - prior): in the
+    # augmented formulation phi_u[0] ~ p = 100, and the min-divergence
+    # covariance H/N - h h^T would otherwise cancel 1e4-sized terms
+    h: jax.Array        # [R]        Σ_u (phi_u - prior)
+    H: jax.Array        # [R, R]     Σ_u (Phi_u + (phi-prior)(phi-prior)^T)
     n_tot: jax.Array    # [C]
     n_utts: jax.Array   # []
 
@@ -215,17 +222,26 @@ def em_accumulate(model: TVModel, pre: Precomp, n, f,
         PPp = (ops.pack_symmetric(Phi)
                + jnp.take(phi, i0, axis=1) * jnp.take(phi, i1, axis=1))
         A = ops.tvm_estep_a(n, PPp, dtype=estep_dtype)         # [C, P]
-        H = ops.unpack_symmetric(jnp.sum(PPp, axis=0), model.rank)
     else:
         PP = Phi + phi[:, :, None] * phi[:, None, :]
         # f32 accumulation pinned (rule NUM001): n/f may arrive bf16
         # under the mixed-precision E-step
-        A = jnp.einsum("uc,urs->crs", n, PP, preferred_element_type=f32)
-        H = jnp.sum(PP, axis=0)
-    B = jnp.einsum("ucd,ur->cdr", f, phi, preferred_element_type=f32)
-    return EMAccum(A=A, B=B, h=jnp.sum(phi, axis=0), H=H,
+        A = jnp.einsum("uc,urs->crs", n, PP, precision=HI,
+                       preferred_element_type=f32)
+    B = jnp.einsum("ucd,ur->cdr", f, phi, precision=HI,
+                   preferred_element_type=f32)
+    dphi = phi - model.prior[None]
+    H = jnp.sum(Phi, axis=0) + jnp.einsum("ur,us->rs", dphi, dphi,
+                                          precision=HI,
+                                          preferred_element_type=f32)
+    return EMAccum(A=A, B=B, h=jnp.sum(dphi, axis=0), H=H,
                    n_tot=jnp.sum(n, axis=0),
                    n_utts=jnp.asarray(n.shape[0], f32))
+
+
+def mean_phi(model: TVModel, acc: EMAccum) -> jax.Array:
+    """Mean posterior phi over the accumulated utterances."""
+    return acc.h / jnp.maximum(acc.n_utts, 1.0) + model.prior
 
 
 def merge_accums(a: EMAccum, b: EMAccum) -> EMAccum:
@@ -285,12 +301,34 @@ def m_step(model: TVModel, acc: EMAccum, S_tot: Optional[jax.Array],
     Sigma = model.Sigma
     if update_sigma and S_tot is not None:
         n_safe = jnp.maximum(acc.n_tot, 1e-6)[:, None, None]
-        TB = jnp.einsum("cdr,cer->cde", T_new, acc.B)
-        Sigma = (S_tot - 0.5 * (TB + TB.transpose(0, 2, 1))) / n_safe
-        D = Sigma.shape[1]
-        Sigma = 0.5 * (Sigma + Sigma.transpose(0, 2, 1)) \
-            + COV_FLOOR * jnp.eye(D)[None]
+        TB = jnp.einsum("cdr,cer->cde", T_new, acc.B, precision=HI)
+        resid = S_tot - 0.5 * (TB + TB.transpose(0, 2, 1))
+        D = resid.shape[1]
+        # Kaldi's variance floor: a fraction of the occupancy-weighted
+        # average residual covariance. A component seen in fewer frames
+        # than D has a rank-deficient estimate; unfloored, its precision
+        # explodes in the next E-step and the iterations diverge to NaN.
+        floor = (VAR_FLOOR_FACTOR * jnp.sum(resid, axis=0)
+                 / jnp.maximum(jnp.sum(acc.n_tot), 1e-6))
+        floor = 0.5 * (floor + floor.T) + COV_FLOOR * jnp.eye(D)
+        Sigma = floor_covariances(resid / n_safe, floor)
     return replace(model, T=T_new.astype(f32), Sigma=Sigma.astype(f32))
+
+
+def floor_covariances(covs, floor):
+    """[C, D, D] symmetric -> each covariance raised to at least ``floor``
+    [D, D] (SPD) in the PSD order (Kaldi's ``SpMatrix::ApplyFloor``):
+    whiten by the floor's Cholesky factor L, clamp the eigenvalues of
+    L⁻¹ Σ L⁻ᵀ at 1, and map back."""
+    D = floor.shape[0]
+    L = jnp.linalg.cholesky(floor)
+    Li = jax.scipy.linalg.solve_triangular(L, jnp.eye(D, dtype=f32),
+                                           lower=True)
+    M = jnp.einsum("ij,cjk,lk->cil", Li, covs, Li, precision=HI)
+    lam, Q = jnp.linalg.eigh(0.5 * (M + M.transpose(0, 2, 1)))
+    M = jnp.einsum("cir,cr,cjr->cij", Q, jnp.maximum(lam, 1.0), Q,
+                   precision=HI)
+    return jnp.einsum("ij,cjk,lk->cil", L, M, L, precision=HI)
 
 
 # ---------------------------------------------------------------------------
@@ -301,8 +339,9 @@ def m_step(model: TVModel, acc: EMAccum, S_tot: Optional[jax.Array],
 def min_divergence(model: TVModel, acc: EMAccum,
                    update_means: bool = False) -> TVModel:
     nu = jnp.maximum(acc.n_utts, 1.0)
-    h = acc.h / nu
-    G = acc.H / nu - h[:, None] * h[None, :]
+    dh = acc.h / nu                    # mean of phi - prior
+    G = acc.H / nu - dh[:, None] * dh[None, :]
+    h = dh + model.prior
     R = model.rank
     G = G + 1e-8 * jnp.eye(R, dtype=f32)
     lam, Q = jnp.linalg.eigh(G)
@@ -311,15 +350,16 @@ def min_divergence(model: TVModel, acc: EMAccum,
     P1_inv = Q * (lam ** 0.5)[None, :]             # Q Λ^{1/2}
 
     if model.formulation == "standard":
-        T_new = jnp.einsum("cdr,rs->cds", model.T, P1_inv)
+        T_new = jnp.einsum("cdr,rs->cds", model.T, P1_inv, precision=HI)
         means = model.means
         if update_means:
             # paper §5: m_c^upd = m_c + T_c h  (old T)
-            means = means + jnp.einsum("cdr,r->cd", model.T, h)
+            means = means + jnp.einsum("cdr,r->cd", model.T, h,
+                                       precision=HI)
         return replace(model, T=T_new.astype(f32), means=means)
 
     # augmented: additionally require P2 P1 h = b e1 (Householder, eqs 8-11)
-    p1h = P1 @ h
+    p1h = jnp.dot(P1, h, precision=HI)
     norm = jnp.linalg.norm(p1h)
     h_t = p1h / jnp.maximum(norm, 1e-10)
     e1 = jnp.zeros((R,), f32).at[0].set(1.0)
@@ -331,8 +371,8 @@ def min_divergence(model: TVModel, acc: EMAccum,
     P2 = jnp.where(degenerate, jnp.eye(R, dtype=f32),
                    jnp.eye(R, dtype=f32) - 2.0 * a[:, None] * a[None, :])
     # T <- T P1^{-1} P2^{-1}; P2 is a reflection: P2^{-1} = P2
-    T_new = jnp.einsum("cdr,rs,st->cdt", model.T, P1_inv, P2)
-    prior = jnp.where(degenerate, P1 @ h, P2 @ (P1 @ h))
+    T_new = jnp.einsum("cdr,rs,st->cdt", model.T, P1_inv, P2, precision=HI)
+    prior = jnp.where(degenerate, p1h, jnp.dot(P2, p1h, precision=HI))
     return replace(model, T=T_new.astype(f32), prior=prior.astype(f32))
 
 

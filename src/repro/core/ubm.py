@@ -19,6 +19,9 @@ import jax
 import jax.numpy as jnp
 
 f32 = jnp.float32
+# f32 contractions run at HIGHEST precision: a TPU's default f32 matmul
+# rounds its inputs to bf16
+HI = jax.lax.Precision.HIGHEST
 _LOG2PI = 1.8378770664093453
 
 
@@ -72,8 +75,9 @@ def diag_loglik_from_coeffs(x, const, lin, quad) -> jax.Array:
     is pinned to f32 (rule NUM001): bf16 feature chunks must widen in
     the MXU, not carry a bf16 partial sum."""
     return (const[None]
-            + jnp.dot(x, lin, preferred_element_type=f32)
-            + jnp.dot(x * x, quad, preferred_element_type=f32)).astype(f32)
+            + jnp.dot(x, lin, precision=HI, preferred_element_type=f32)
+            + jnp.dot(x * x, quad, precision=HI,
+                      preferred_element_type=f32)).astype(f32)
 
 
 def diag_loglik(gmm: DiagGMM, x) -> jax.Array:
@@ -96,9 +100,10 @@ def full_precisions(gmm: FullGMM) -> Tuple[jax.Array, jax.Array, jax.Array]:
     P = 0.5 * (P + P.transpose(0, 2, 1))
     logdet = 2.0 * jnp.sum(
         jnp.log(jnp.diagonal(chol, axis1=1, axis2=2)), axis=1)
-    lin = jnp.einsum("cij,cj->ci", P, gmm.means)
+    lin = jnp.einsum("cij,cj->ci", P, gmm.means, precision=HI)
     const = (-0.5 * (logdet + gmm.means.shape[1] * _LOG2PI
-                     + jnp.einsum("ci,ci->c", gmm.means, lin))
+                     + jnp.einsum("ci,ci->c", gmm.means, lin,
+                                  precision=HI))
              + jnp.log(gmm.weights))
     return const.astype(f32), lin.astype(f32), P.astype(f32)
 
@@ -229,7 +234,7 @@ def psd_floor(covs, floor: float = VAR_FLOOR) -> jax.Array:
     covs = 0.5 * (covs + jnp.swapaxes(covs, -1, -2))
     lam, Q = jnp.linalg.eigh(covs)
     lam = jnp.maximum(lam, floor)
-    return jnp.einsum("...ir,...r,...jr->...ij", Q, lam, Q)
+    return jnp.einsum("...ir,...r,...jr->...ij", Q, lam, Q, precision=HI)
 
 
 def full_from_diag(gmm: DiagGMM) -> FullGMM:

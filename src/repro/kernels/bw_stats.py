@@ -43,7 +43,7 @@ def _kernel(g_ref, x_ref, n_ref, f_ref, s_ref):
 @functools.partial(jax.jit, static_argnames=("block_f", "block_c",
                                              "interpret"))
 def bw_stats(gamma, x, *, block_f: int = 256, block_c: int = 128,
-             interpret: bool = True):
+             interpret: bool = False):
     """gamma: [F, C]; x: [F, D] -> (n [C], f [C, D], S [C, D*D])."""
     F, C = gamma.shape
     D = x.shape[1]

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _COMPILER_PARAMS
 
 f32 = jnp.float32
 _NEG = -1e30
@@ -66,7 +65,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc, m, l, *, block_q, block_k,
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k",
                                              "interpret"))
 def flash_attention(q, k, v, *, block_q: int = 256, block_k: int = 256,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """Causal GQA attention. q: [B, S, H, hd]; k, v: [B, S, KVH, hd]."""
     B, S, H, hd = q.shape
     KVH = k.shape[2]
@@ -92,7 +91,7 @@ def flash_attention(q, k, v, *, block_q: int = 256, block_k: int = 256,
             pltpu.VMEM((bq,), f32),
             pltpu.VMEM((bq,), f32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -144,7 +144,7 @@ def _kernel(x_ref, dconst_ref, dlin_ref, dquad_ref, sexp_ref, a_ref,
     "top_k", "block_f", "dma_depth", "interpret"))
 def gmm_align(x, dconst, dlin, dquad, sexp, A2, *, top_k: int,
               block_f: int = BLOCK_F, dma_depth: int = DMA_DEPTH,
-              interpret: bool = True):
+              interpret: bool = False):
     """x: [F, D]; dconst: [1, C], dlin: [D, C], dquad: [D, C] diag
     preselect coefficients (score = const + x·lin + x²·quad); sexp:
     [D*D, E2] quadratic-expansion operand (``ops.align_expand_operand``);
@@ -170,7 +170,7 @@ def gmm_align(x, dconst, dlin, dquad, sexp, A2, *, top_k: int,
             pl.BlockSpec((D, C), lambda i: (0, 0)),
             pl.BlockSpec((D, C), lambda i: (0, 0)),
             pl.BlockSpec((D * D, E2), lambda i: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),        # A2 stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),        # A2 stays in HBM
         ],
         out_specs=[
             pl.BlockSpec((bf, top_k), lambda i: (i, 0)),

@@ -1,8 +1,10 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``use_pallas(True/False)`` toggles between kernels (TPU; interpret mode on
-CPU for validation) and the pure-jnp references. The i-vector core calls
-these wrappers, so the kernel path is a drop-in.
+The backend picks the path: on a TPU every wrapper runs its compiled
+Pallas kernel, elsewhere the pure-jnp reference (`ref.py`). Tests steer
+that choice with ``use_pallas(True/False)``; on the CPU the forced
+kernels run in Pallas interpret mode. The i-vector core calls these
+wrappers, so the kernel path is a drop-in.
 """
 from __future__ import annotations
 
@@ -21,12 +23,15 @@ from repro.kernels import tvm_estep as _te
 
 f32 = jnp.float32
 
-_USE_PALLAS = contextvars.ContextVar("repro_use_pallas", default=False)
-_INTERPRET = contextvars.ContextVar("repro_pallas_interpret", default=True)
+# None: decided by the backend; True/False: forced by ``use_pallas``
+_USE_PALLAS = contextvars.ContextVar("repro_use_pallas", default=None)
+_INTERPRET = contextvars.ContextVar("repro_pallas_interpret", default=False)
 
 
 @contextlib.contextmanager
 def use_pallas(enable: bool = True, interpret: bool = True):
+    """Force the kernels on (``interpret`` runs them in Pallas interpret
+    mode, for the CPU) or off, overriding the backend's choice."""
     t1 = _USE_PALLAS.set(enable)
     t2 = _INTERPRET.set(interpret)
     try:
@@ -36,12 +41,17 @@ def use_pallas(enable: bool = True, interpret: bool = True):
         _INTERPRET.reset(t2)
 
 
+def _kernels_on() -> bool:
+    forced = _USE_PALLAS.get()
+    return jax.default_backend() == "tpu" if forced is None else forced
+
+
 def _ceil_to(n: int, b: int) -> int:
     return -(-n // b) * b
 
 
 def gmm_loglik(x, const, lin, P_flat, **kw):
-    if _USE_PALLAS.get():
+    if _kernels_on():
         # The Pallas grid needs F and C to divide into whole blocks; ragged
         # shapes (variable-length serving traffic) are zero-padded here and
         # the result sliced back — padding rows/components never escape.
@@ -55,7 +65,7 @@ def gmm_loglik(x, const, lin, P_flat, **kw):
             const = jnp.pad(const, (0, Cp - C))
             lin = jnp.pad(lin, ((0, 0), (0, Cp - C)))
             P_flat = jnp.pad(P_flat, ((0, Cp - C), (0, 0)))
-        out = _gl.gmm_loglik(x, const, lin, P_flat,
+        out = _gl.gmm_loglik(x, const[None, :], lin, P_flat,
                              interpret=_INTERPRET.get(), **kw)
         return out[:F, :C] if (Fp, Cp) != (F, C) else out
     return ref.gmm_loglik(x, const, lin, P_flat)
@@ -67,26 +77,29 @@ def gmm_rescore(x, sel, const, lin, P_flat, pack=None, **kw):
     x: [F, D]; sel: [F, K] component ids; const/lin/P_flat as in
     ``gmm_loglik``. ``pack`` optionally supplies the pre-built
     ``ref.rescore_pack`` matrix (serving caches it per session) so the
-    Pallas path skips the concat. Ragged F is zero-padded to the kernel's
-    frame-tile and sliced back; indices are clipped into [0, C) so
-    padding rows (and garbage preselections from masked frames) can
-    never DMA out of bounds.
+    Pallas path skips the concat. The kernel scores each gathered row
+    against the frame expansion [1 | x | -0.5·vec(x xᵀ)] built here.
+    Ragged F is zero-padded to the kernel's frame-tile and sliced back;
+    indices are clipped into [0, C) so padding rows (and garbage
+    preselections from masked frames) can never DMA out of bounds.
     """
-    if _USE_PALLAS.get():
-        F = x.shape[0]
+    if _kernels_on():
+        F, D = x.shape
         C = const.shape[0]
         A = ref.rescore_pack(const, lin, P_flat) if pack is None else pack
-        E = A.shape[1]
-        Ep = _ceil_to(E, 128)
-        if Ep != E:
-            A = jnp.pad(A, ((0, 0), (0, Ep - E)))
+        Ep = _ceil_to(A.shape[1], 128)
+        A = jnp.pad(A, ((0, 0), (0, Ep - A.shape[1]))).reshape(C, 1, Ep)
+        x = x.astype(f32)
+        x2 = (x[:, :, None] * x[:, None, :]).reshape(F, D * D)
+        xe = jnp.concatenate([jnp.ones((F, 1), f32), x, -0.5 * x2], axis=1)
+        xe = jnp.pad(xe, ((0, 0), (0, Ep - xe.shape[1])))
         bf = min(kw.get("block_f", _gr.BLOCK_F), F)
         Fp = _ceil_to(F, bf)
         sel = jnp.clip(sel.astype(jnp.int32), 0, C - 1)
         if Fp != F:
-            x = jnp.pad(x, ((0, Fp - F), (0, 0)))
+            xe = jnp.pad(xe, ((0, Fp - F), (0, 0)))
             sel = jnp.pad(sel, ((0, Fp - F), (0, 0)))
-        out = _gr.gmm_rescore(x, sel, A, interpret=_INTERPRET.get(), **kw)
+        out = _gr.gmm_rescore(xe, sel, A, interpret=_INTERPRET.get(), **kw)
         return out[:F] if Fp != F else out
     return ref.gmm_rescore(x, sel, const, lin, P_flat)
 
@@ -141,8 +154,9 @@ def gmm_align(x, dconst, dlin, dquad, A2, *, top_k: int, block_f=None,
     coalesced gather + packed rescore -> (sel_ll [F, K], sel [F, K]).
 
     Routes to the single fused Pallas kernel (`kernels/gmm_align.py`)
-    under ``use_pallas``; the jnp path composes the same stages (shared
-    ``lax.top_k`` preselect + ``gmm_rescore_fused``) so both produce the
+    where kernels run (see the module docstring); the jnp path composes
+    the same stages (shared ``lax.top_k`` preselect +
+    ``gmm_rescore_fused``) so both produce the
     identical selected set and scores to f32 rounding. dconst: [C];
     dlin/dquad: [D, C] diag score coefficients; A2: [C, E2].
     """
@@ -153,7 +167,7 @@ def gmm_align(x, dconst, dlin, dquad, A2, *, top_k: int, block_f=None,
         tune = autotune_align(C=C, K=top_k, D=D)
         block_f = block_f or tune.block_f
         dma_depth = dma_depth or tune.dma_depth
-    if _USE_PALLAS.get():
+    if _kernels_on():
         from repro.kernels import gmm_align as _ga
         E2 = A2.shape[1]
         bf = max(1, min(block_f, F))
@@ -167,8 +181,10 @@ def gmm_align(x, dconst, dlin, dquad, A2, *, top_k: int, block_f=None,
             interpret=_INTERPRET.get(), **kw)
         return (ll[:F], sel[:F]) if Fp != F else (ll, sel)
     scores = (dconst[None]
-              + jnp.dot(x, dlin, preferred_element_type=f32)
-              + jnp.dot(x * x, dquad, preferred_element_type=f32))
+              + jnp.dot(x, dlin, precision=ref.HI,
+                        preferred_element_type=f32)
+              + jnp.dot(x * x, dquad, precision=ref.HI,
+                        preferred_element_type=f32))
     _, sel = jax.lax.top_k(scores, top_k)
     sel = sel.astype(jnp.int32)
     ll = gmm_rescore_fused(x, sel, A2, block_f=block_f)
@@ -179,9 +195,42 @@ tri_inverse = ref.tri_inverse
 
 
 def bw_stats(gamma, x, **kw):
-    if _USE_PALLAS.get():
+    if _kernels_on():
         return _bw.bw_stats(gamma, x, interpret=_INTERPRET.get(), **kw)
     return ref.bw_stats(gamma, x)
+
+
+def second_moments(x, values, indices, C: int):
+    """Second-order Baum-Welch moments of sparse posteriors -> [C, D*D].
+
+    x: [N, D]; values/indices: [N, K] (indices in [0, C)). Where kernels
+    run, the N·K (frame, slot) pairs are sorted by component and each
+    component's weighted frames contract with its frames as one grouped
+    matmul (``lax.ragged_dot_general`` with a ragged contracting axis, a
+    Mosaic kernel on TPU): 2·N·K·D² FLOPs and [N·K, D] operands. The
+    reference scatter-add builds an [N·K, D²] update buffer instead,
+    41 GB for the trainer's 512-utterance chunk at D=72, which XLA:TPU
+    refuses to compile for a 16 GB chip. Elsewhere the scatter runs: the
+    CPU lowers the grouped matmul to a dense masked one.
+    """
+    if not _kernels_on():
+        return ref.second_moments(x, values, indices, C)
+    N, D = x.shape
+    K = values.shape[1]
+    comp = indices.reshape(-1).astype(jnp.int32)
+    order = jnp.argsort(comp)
+    xs = jnp.take(x.astype(f32), order // K, axis=0)         # [N*K, D]
+    w = jnp.take(values.reshape(-1).astype(f32), order)
+    bounds = jnp.searchsorted(jnp.take(comp, order),
+                              jnp.arange(C + 1, dtype=jnp.int32))
+    sizes = jnp.diff(bounds).astype(jnp.int32)               # [C]
+    dn = jax.lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(([0], [0]), ([], [])),
+        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+    S = jax.lax.ragged_dot_general(
+        xs * w[:, None], xs, sizes, dn, precision=ref.HI,
+        preferred_element_type=f32)                          # [C, D, D]
+    return S.reshape(C, D * D)
 
 
 def _estep_cast(a, b, dtype):
@@ -218,7 +267,7 @@ def tvm_estep_l(n, U_packed, *, dtype: str = "float32", **kw):
     sliced back, mirroring ``gmm_loglik``.
     """
     n, U_packed = _estep_cast(n, U_packed, dtype)
-    if _USE_PALLAS.get():
+    if _kernels_on():
         U, C = n.shape
         P = U_packed.shape[1]
         bu = min(kw.get("block_u", _te.BLOCK_U), U)
@@ -238,7 +287,7 @@ def tvm_estep_a(n, PP_packed, *, dtype: str = "float32", **kw):
     contribute exactly nothing).
     """
     n, PP_packed = _estep_cast(n, PP_packed, dtype)
-    if _USE_PALLAS.get():
+    if _kernels_on():
         U, C = n.shape
         P = PP_packed.shape[1]
         bu = min(kw.get("block_u", _te.BLOCK_U), U)
@@ -256,7 +305,7 @@ def tvm_estep_a(n, PP_packed, *, dtype: str = "float32", **kw):
 
 
 def flash_attention(q, k, v, **kw):
-    if _USE_PALLAS.get():
+    if _kernels_on():
         return _fa.flash_attention(q, k, v, interpret=_INTERPRET.get(), **kw)
     return ref.flash_attention(q, k, v)
 
@@ -268,7 +317,7 @@ unpack_symmetric = ref.unpack_symmetric
 def selective_scan(dt, dx, A, Bc, Cc, **kw):
     from repro.kernels import selective_scan as _ss
     from repro.models.mamba import _ssm_scan
-    if _USE_PALLAS.get():
+    if _kernels_on():
         return _ss.selective_scan(dt, dx, A, Bc, Cc,
                                   interpret=_INTERPRET.get(), **kw)
     h0 = jnp.zeros((dt.shape[0], dt.shape[2], A.shape[1]), jnp.float32)

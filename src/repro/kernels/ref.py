@@ -6,6 +6,9 @@ import jax
 import jax.numpy as jnp
 
 f32 = jnp.float32
+# f32 contractions run at HIGHEST precision: a TPU's default f32 matmul
+# rounds its inputs to bf16
+HI = jax.lax.Precision.HIGHEST
 
 
 def gmm_loglik(x, const, lin, P_flat):
@@ -18,9 +21,9 @@ def gmm_loglik(x, const, lin, P_flat):
     F, D = x.shape
     x2 = (x[:, :, None] * x[:, None, :]).reshape(F, D * D)
     return (const[None]
-            + jnp.dot(x, lin, preferred_element_type=f32)
-            - 0.5 * jnp.dot(x2, P_flat.T, preferred_element_type=f32)
-            ).astype(f32)
+            + jnp.dot(x, lin, precision=HI, preferred_element_type=f32)
+            - 0.5 * jnp.dot(x2, P_flat.T, precision=HI,
+                            preferred_element_type=f32)).astype(f32)
 
 
 def gmm_rescore(x, sel, const, lin, P_flat):
@@ -43,9 +46,9 @@ def gmm_rescore(x, sel, const, lin, P_flat):
     lin_g = jnp.take(lin.T, sel, axis=0)                    # [F, K, D]
     P_g = jnp.take(P_flat, sel, axis=0)                     # [F, K, D*D]
     return (jnp.take(const, sel)
-            + jnp.einsum("fd,fkd->fk", x, lin_g,
+            + jnp.einsum("fd,fkd->fk", x, lin_g, precision=HI,
                          preferred_element_type=f32)
-            - 0.5 * jnp.einsum("fe,fke->fk", x2, P_g,
+            - 0.5 * jnp.einsum("fe,fke->fk", x2, P_g, precision=HI,
                                preferred_element_type=f32)).astype(f32)
 
 
@@ -124,7 +127,8 @@ def gmm_rescore_fused(x, sel, A2, *, strategy="full", block_f=8):
     Fn, K = sel.shape
     xe = expand_quadratic(x)                                 # [F, E2]
     if strategy == "full":
-        ll = jnp.dot(xe, A2.T, preferred_element_type=f32)   # [F, C]
+        ll = jnp.dot(xe, A2.T, precision=HI,
+                     preferred_element_type=f32)             # [F, C]
         return jnp.take_along_axis(ll, sel, axis=1).astype(f32)
     if strategy != "union":
         raise ValueError(f"strategy must be 'full' or 'union': {strategy!r}")
@@ -139,7 +143,7 @@ def gmm_rescore_fused(x, sel, A2, *, strategy="full", block_f=8):
     rows = jnp.take(A2, ids_sorted, axis=0)           # [T, BF*K, E2]
     scores = jax.lax.dot_general(
         xe.reshape(T, block_f, E2), rows,
-        (((2,), (2,)), ((0,), (0,))),
+        (((2,), (2,)), ((0,), (0,))), precision=HI,
         preferred_element_type=f32)                   # [T, BF, BF*K]
     out = jnp.take_along_axis(scores, inv.reshape(T, block_f, K), axis=2)
     return out.reshape(Fn, K).astype(f32)
@@ -170,8 +174,9 @@ def tri_inverse(G, block: int = 16):
         M = -N
         p = 1
         while p < R:
-            M = jnp.matmul(M, M, preferred_element_type=f32)
-            X = X + jnp.matmul(M, X, preferred_element_type=f32)
+            M = jnp.matmul(M, M, precision=HI, preferred_element_type=f32)
+            X = X + jnp.matmul(M, X, precision=HI,
+                               preferred_element_type=f32)
             p *= 2
         return X * Dinv[..., None, :]
     h = (R + 1) // 2
@@ -180,8 +185,8 @@ def tri_inverse(G, block: int = 16):
     C_ = G[..., h:, h:]
     Ai = tri_inverse(A, block)
     Ci = tri_inverse(C_, block)
-    BAi = jnp.matmul(B, Ai, preferred_element_type=f32)
-    low = -jnp.matmul(Ci, BAi, preferred_element_type=f32)
+    BAi = jnp.matmul(B, Ai, precision=HI, preferred_element_type=f32)
+    low = -jnp.matmul(Ci, BAi, precision=HI, preferred_element_type=f32)
     top = jnp.concatenate([Ai, jnp.zeros(A.shape[:-2] + (h, R - h),
                                          dtype=G.dtype)], axis=-1)
     bot = jnp.concatenate([low, Ci], axis=-1)
@@ -197,9 +202,23 @@ def bw_stats(gamma, x):
     F, D = x.shape
     x2 = (x[:, :, None] * x[:, None, :]).reshape(F, D * D)
     n = jnp.sum(gamma, axis=0)
-    f = jnp.dot(gamma.T, x, preferred_element_type=f32)
-    S = jnp.dot(gamma.T, x2, preferred_element_type=f32)
+    f = jnp.dot(gamma.T, x, precision=HI, preferred_element_type=f32)
+    S = jnp.dot(gamma.T, x2, precision=HI, preferred_element_type=f32)
     return n.astype(f32), f.astype(f32), S.astype(f32)
+
+
+def second_moments(x, values, indices, C: int):
+    """Second-order Baum-Welch moments of sparse posteriors by scatter-add.
+
+    x: [N, D] frames; values/indices: [N, K] posteriors of the selected
+    components. Returns [C, D*D] with row c = sum over (frame, slot)
+    pairs selecting c of value * vec(x xᵀ) (row-major).
+    """
+    N, D = x.shape
+    K = values.shape[1]
+    x2 = (x[:, :, None] * x[:, None, :]).reshape(N, D * D)
+    x2w = (values[:, :, None] * x2[:, None, :]).reshape(N * K, D * D)
+    return jnp.zeros((C, D * D), f32).at[indices.reshape(-1)].add(x2w)
 
 
 def tvm_estep_l(n, U_packed):
@@ -211,7 +230,8 @@ def tvm_estep_l(n, U_packed):
     matmul FLOPs versus the dense [C, R, R] form. bf16 inputs accumulate
     in f32 (``preferred_element_type``), same contract as the kernel.
     """
-    return jnp.dot(n, U_packed, preferred_element_type=f32).astype(f32)
+    return jnp.dot(n, U_packed, precision=HI,
+                   preferred_element_type=f32).astype(f32)
 
 
 def tvm_estep_a(n, PP_packed):
@@ -221,7 +241,8 @@ def tvm_estep_a(n, PP_packed):
     moments Phi_u + φ_u φ_uᵀ. Returns [C, P] f32 — the packed M-step
     operand A_c = Σ_u n_uc (Phi_u + φ_u φ_uᵀ).
     """
-    return jnp.dot(n.T, PP_packed, preferred_element_type=f32).astype(f32)
+    return jnp.dot(n.T, PP_packed, precision=HI,
+                   preferred_element_type=f32).astype(f32)
 
 
 def _packed_index_map(R):

@@ -120,7 +120,7 @@ def _gmm_loglik_instance(cfg: dict) -> KernelInstance:
         grid=grid,
         inputs=(
             BlockMap("x", (Fp, D), (bf, D), lambda i, j: (i, 0)),
-            BlockMap("const", (Cp,), (bc,), lambda i, j: (j,)),
+            BlockMap("const", (1, Cp), (1, bc), lambda i, j: (0, j)),
             BlockMap("lin", (D, Cp), (D, bc), lambda i, j: (0, j)),
             BlockMap("P_flat", (Cp, D * D), (bc, D * D),
                      lambda i, j: (j, 0)),
@@ -153,19 +153,23 @@ def _gmm_rescore_instance(cfg: dict) -> KernelInstance:
     E = _ceil_to(1 + D + D * D, 128)        # ops.py pads E to a lane multiple
     bf = min(cfg.get("block_f", _gr.BLOCK_F), F)
     Fp = _ceil_to(F, bf)
-    depth = max(1, min(cfg.get("dma_depth", _gr.DMA_DEPTH), bf * K))
+    n = bf * K
+    depth = max(1, min(cfg.get("dma_depth", _gr.DMA_DEPTH), n))
+    T = Fp // bf
     return KernelInstance(
-        grid=(Fp // bf,),
+        grid=(T,),
         inputs=(
-            BlockMap("sel", (Fp, K), (bf, K), lambda i: (i, 0),
+            BlockMap("ids", (T, 1, n), (1, 1, n), lambda i: (i, 0, 0),
                      memory="smem", dtype="int32"),
-            BlockMap("x", (Fp, D), (bf, D), lambda i: (i, 0)),
-            BlockMap("A", (C, E), None, None, memory="any"),
+            BlockMap("dst", (T, 1, n), (1, 1, n), lambda i: (i, 0, 0),
+                     memory="smem", dtype="int32"),
+            BlockMap("xe", (Fp, E), (bf, E), lambda i: (i, 0)),
+            BlockMap("A", (C, 1, E), None, None, memory="any"),
         ),
         outputs=(
             BlockMap("out", (Fp, K), (bf, K), lambda i: (i, 0)),
         ),
-        scratch_bytes=(bf * K * E + bf * K) * 4,
+        scratch_bytes=n * E * 4,
         rings=(DmaRing("sem", depth),),
     )
 

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _COMPILER_PARAMS
 
 f32 = jnp.float32
 
@@ -54,7 +53,7 @@ def _kernel(dt_ref, dx_ref, A_ref, B_ref, C_ref, y_ref, h, *, block_t):
 @functools.partial(jax.jit, static_argnames=("block_t", "block_d",
                                              "interpret"))
 def selective_scan(dt, dx, A, Bc, Cc, *, block_t: int = 128,
-                   block_d: int = 512, interpret: bool = True):
+                   block_d: int = 512, interpret: bool = False):
     """dt, dx: [B, T, di]; A: [di, ds]; Bc, Cc: [B, T, ds] -> y [B, T, di].
 
     h_t = exp(dt_t A) h_{t-1} + (dt_t x_t) B_t;  y_t = C_t . h_t
@@ -79,7 +78,7 @@ def selective_scan(dt, dx, A, Bc, Cc, *, block_t: int = 128,
         out_specs=pl.BlockSpec((1, bt, bd), lambda b, d, t: (b, t, d)),
         out_shape=jax.ShapeDtypeStruct((B, T, di), dt.dtype),
         scratch_shapes=[pltpu.VMEM((bd, ds), f32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(dt, dx, A, Bc, Cc)

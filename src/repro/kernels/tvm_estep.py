@@ -16,8 +16,8 @@ accumulates in f32 via ``preferred_element_type``. Grids:
 Shapes must divide the blocks — the `ops.py` wrappers zero-pad ragged
 U/C/P to block multiples and slice back (zero rows/columns contribute
 exactly nothing to a sum-reduction), mirroring `ops.gmm_loglik`.
-Compiled by default (`interpret=False`); the ops wrappers route through
-interpret mode on CPU.
+f32 inputs contract at HIGHEST precision (the TPU's default f32 matmul
+rounds its inputs to bf16); bf16 inputs use the MXU's native pass.
 """
 from __future__ import annotations
 
@@ -35,14 +35,21 @@ BLOCK_P = 256   # packed-triangle tile
 BLOCK_C = 128   # component tile (L reduction / A rows)
 
 
-def _matmul_kernel(a_ref, b_ref, out_ref):
+def _matmul_kernel(a_ref, b_ref, out_ref, *, widen: bool):
     """out[i, j] += a[i, :] @ b[:, j], f32 accumulation over grid axis 2.
 
     Inputs stay in their storage dtype (f32 or bf16); the MXU widens to
     f32 via ``preferred_element_type`` — the mixed-precision contract.
+    ``widen`` casts bf16 blocks to f32 first, for interpret mode: the CPU
+    runtime has no bf16 x bf16 -> f32 dot, and the cast is exact (a
+    product of two 8-bit mantissas fits in f32's 24).
     """
     k = pl.program_id(2)
-    part = jax.lax.dot(a_ref[...], b_ref[...], preferred_element_type=f32)
+    a, b = a_ref[...], b_ref[...]
+    prec = jax.lax.Precision.HIGHEST if a.dtype == f32 else None
+    if widen:
+        a, b = a.astype(f32), b.astype(f32)
+    part = jax.lax.dot(a, b, precision=prec, preferred_element_type=f32)
 
     @pl.when(k == 0)
     def _init():
@@ -61,7 +68,7 @@ def _packed_matmul(a, b, *, bm: int, bp: int, bk: int, interpret: bool):
     assert M % bm == 0 and P % bp == 0 and K % bk == 0, (M, P, K, bm, bp, bk)
     grid = (M // bm, P // bp, K // bk)
     return pl.pallas_call(
-        _matmul_kernel,
+        functools.partial(_matmul_kernel, widen=interpret),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),

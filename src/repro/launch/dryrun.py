@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.analysis.roofline import roofline_from_compiled
 from repro.configs import ALL_SHAPES, ARCH_IDS, get_config, get_shape
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models import api
 from repro.sharding import make_rules, use_rules
@@ -151,6 +152,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

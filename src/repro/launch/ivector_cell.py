@@ -146,7 +146,7 @@ def model_flops(cfg, n_utts: int) -> float:
         # 'union' strategy (C/(BF·K) cut) or all C for 'full'
         from repro.analysis.roofline import autotune_align
         E2 = 1 + D + D * (D + 1) // 2
-        tune = autotune_align(C, K, D, backend="tpu")
+        tune = autotune_align(C, K, D, device_kind="TPU v5 lite")
         u = min(tune.block_f * K, C) if tune.strategy == "union" else C
         align += 2.0 * F * u * E2
     else:

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, ShapeConfig
+from repro.launch.cache import enable_compile_cache
 from repro.models import api
 
 
@@ -28,6 +29,7 @@ def pad_cache(cache, target_len: int):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma-2b")
     ap.add_argument("--smoke", action="store_true")

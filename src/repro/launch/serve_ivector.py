@@ -31,23 +31,29 @@ from repro.core import trainer as TR
 from repro.core import ubm as U
 from repro.data.speech import (FRAME_RATE, SpeechDataConfig,
                                build_ragged_dataset)
+from repro.launch.cache import enable_compile_cache
 from repro.serving import (AdmissionQueue, IVectorExtractor, QueueFull,
                            ServingConfig, SessionConfig, SessionStore)
 
 
-def build_state(cfg, data_cfg, train_iters: int):
-    """Synthetic ragged corpus + quickly-trained (UBM, TVM) pair."""
+def build_state(cfg, data_cfg, train_iters: int, *, seed: int = 0,
+                callback=None):
+    """Synthetic ragged corpus + quickly-trained (UBM, TVM) pair.
+
+    The UBM takes 4 diag + 2 full-covariance EM iterations with the
+    config's top-K pruning and rescoring mode; ``callback`` goes to
+    `trainer.train`.
+    """
     utts, labels = build_ragged_dataset(data_cfg)
     frames = np.concatenate([np.asarray(u) for u in utts], axis=0)
-    # demo driver: the fixed seed keeps the served model reproducible
     ubm = U.train_ubm(jax.numpy.asarray(frames), cfg.n_components,
-                      # repro-check: disable=SRC002
-                      jax.random.PRNGKey(0), diag_iters=4, full_iters=2)
+                      jax.random.PRNGKey(seed), diag_iters=4, full_iters=2,
+                      top_k=cfg.posterior_top_k, rescore=cfg.rescore)
     # fixed-length training block (the service is where ragged lengths live)
     fixed = np.stack([np.asarray(u)[:data_cfg.min_frames_per_utt]
                       for u in utts])
     state = TR.train(cfg, ubm, jax.numpy.asarray(fixed),
-                     n_iters=train_iters)
+                     n_iters=train_iters, callback=callback)
     return state, utts, labels
 
 
@@ -110,6 +116,7 @@ def serve_streaming(ex, utts, args):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--bundle", default=None,
@@ -175,6 +182,7 @@ def main():
     # real requests, so a broken fused kernel demotes here, not mid-load
     health = ex.health_check()
     print(f"  readiness: ok={health['ok']} mode={health['mode']} "
+          f"degradations={health['degradations']} "
           f"canary latency {health['latency_s']:.3f}s")
     if not health["ok"]:
         raise SystemExit(f"serving session unhealthy: {health}")

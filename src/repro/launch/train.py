@@ -16,10 +16,12 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.data.tokens import TokenPipeline, TokenPipelineConfig
 from repro.distributed.fault_tolerance import run_supervised
+from repro.launch.cache import enable_compile_cache
 from repro.models import api
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-1.6b")
     ap.add_argument("--smoke", action="store_true",

@@ -13,7 +13,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.sharding import tag
-from repro.kernels import compat
 
 f32 = jnp.float32
 
@@ -256,7 +255,7 @@ def ring_attention(q, k, v):
         return out.reshape(Bl, S_loc, H, hd).astype(q_loc.dtype)
 
     spec_q = jax.sharding.PartitionSpec(data_axes, "model", None, None)
-    fn = compat.shard_map(block, mesh=mesh,
+    fn = jax.shard_map(block, mesh=mesh,
                        in_specs=(spec_q, spec_q, spec_q),
                        out_specs=spec_q, check_vma=False)
     return fn(q, k, v)

@@ -23,7 +23,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.sharding import active_rules
 from repro.models.layers import ParamTable, f32
-from repro.kernels import compat
 
 
 def moe_table(cfg, prefix, L) -> ParamTable:
@@ -195,7 +194,7 @@ def moe_a2a(cfg, p, x, sp: bool):
     if gated:
         pp["w_gate"] = p["w_gate"]
         pp_specs["w_gate"] = P("model")
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         block, mesh=mesh, in_specs=(tok_spec, pp_specs),
         out_specs=(tok_spec, P()), check_vma=False)
     y, aux = fn(x, pp)

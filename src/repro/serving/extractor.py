@@ -203,8 +203,9 @@ class IVectorExtractor:
 
     def _run_batch(self, feats, mask) -> np.ndarray:
         """One device call at the session's current mode, demoting down
-        the rescore ladder on failure instead of raising (DESIGN.md §13).
-        Only a failure of the reference 'dense' path propagates."""
+        the rescore ladder on failure instead of raising (DESIGN.md §13),
+        with a warning naming the mode and the exception. Only a failure
+        of the reference 'dense' path propagates."""
         while True:
             mode = self.mode
             try:
@@ -215,10 +216,11 @@ class IVectorExtractor:
                     self._fns[mode] = self._make_fn(mode)
                 return np.asarray(self._fns[mode](
                     self._pack, self.model, self._tv_pre, feats, mask))
-            except Exception:
+            except Exception as e:
                 nxt = EN.degrade_rescore(mode)
                 if nxt is None:
                     raise
+                EN.warn_demotion(mode, nxt, e)
                 self.mode = nxt
                 self.stats["mode"] = nxt
                 self.stats["degradations"] += 1

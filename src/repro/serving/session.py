@@ -492,7 +492,8 @@ class SessionStore:
     def _run_chunk(self, b: _Binding, feats, mask):
         """One chunk through the engine at the binding's current mode,
         demoting down the rescore ladder on kernel failure instead of
-        raising (the batch extractor's contract, DESIGN.md §13)."""
+        raising, with a warning (the batch extractor's contract,
+        DESIGN.md §13)."""
         while True:
             mode = b.mode
             try:
@@ -502,10 +503,11 @@ class SessionStore:
                 if mode not in b.chunk_fns:
                     b.chunk_fns[mode] = self._make_chunk_fn(b, mode)
                 return b.chunk_fns[mode](b.pack, feats, mask)
-            except Exception:
+            except Exception as e:
                 nxt = EN.degrade_rescore(mode)
                 if nxt is None:
                     raise
+                EN.warn_demotion(mode, nxt, e)
                 b.mode = nxt
                 self.stats["degradations"] += 1
 

@@ -131,7 +131,7 @@ class TestJaxprRules:
         assert "NUM003" in _ids(found)
 
     def test_num004_f64_leak(self):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             x = jnp.zeros((4,), jnp.float64)
             found = check_jaxpr(lambda v: (v * 2.0).sum(), x)
         assert "NUM004" in _ids(found)

@@ -41,7 +41,8 @@ def test_moe_a2a_matches_dense():
              L.table_init(table, jax.random.PRNGKey(0), jnp.float32).items()}
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))
         want, aux_d = MOE.moe_dense(cfg, p, x)
-        mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+        mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         rules = make_rules(mesh, cfg, None)
         with use_rules(rules):
             got_sp, aux1 = jax.jit(lambda x, p: MOE.moe_a2a(cfg, p, x, True))(x, p)
@@ -70,7 +71,8 @@ def test_sharded_train_step_matches_single_device():
                                               0, cfg.vocab_size)}
         step = api.make_train_step(cfg)
         ref_state, ref_m = jax.jit(step)(state, batch)
-        mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+        mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         shape = ShapeConfig('train_4k', 64, 8, 'train')
         rules = make_rules(mesh, cfg, shape)
         with use_rules(rules):
@@ -98,12 +100,14 @@ def test_elastic_remesh_restore(tmp_path):
         cfg = get_config('gemma-2b', smoke=True)
         params = api.init_params(cfg, jax.random.PRNGKey(0))
         axes = api.params_axes(cfg)
-        mesh_a = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh_a = jax.make_mesh((4, 2), ('data', 'model'),
+                               axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rules_a = make_rules(mesh_a, cfg, None)
         sharded = {{k: jax.device_put(v, rules_a.sharding(v.shape, axes[k]))
                    for k, v in params.items()}}
         save({str(tmp_path)!r}, 1, sharded, logical_axes=axes)
-        mesh_b = jax.make_mesh((2, 2), ('data', 'model'))
+        mesh_b = jax.make_mesh((2, 2), ('data', 'model'),
+                               axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rules_b = make_rules(mesh_b, cfg, None)
         got, step, _ = restore({str(tmp_path)!r}, params, rules=rules_b)
         for k in params:
@@ -132,7 +136,8 @@ def test_ring_attention_matches_blockwise():
         k = jax.random.normal(jax.random.fold_in(k0, 1), (B, S, KVH, hd))
         v = jax.random.normal(jax.random.fold_in(k0, 2), (B, S, KVH, hd))
         want = L.blockwise_causal_attention(q, k, v)
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = get_config('whisper-large-v3', smoke=True)
         rules = make_rules(mesh, cfg, None)
         with use_rules(rules):
@@ -157,7 +162,8 @@ def test_mini_dryrun_multipod_compiles():
         from repro.analysis.hlo_cost import analyze_hlo
         cfg = get_config('arctic-480b', smoke=True)
         shape = ShapeConfig('train', 64, 8, 'train')
-        mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+        mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         rules = make_rules(mesh, cfg, shape)
         batch = api.input_specs(cfg, shape)
         st = api.state_struct(cfg)

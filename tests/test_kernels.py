@@ -169,7 +169,7 @@ def test_gmm_align_wrapper_autotuned_configs():
     C, D, K, F = 48, 8, 6, 32
     x, dconst, dlin, dquad, _, A2 = _fused_inputs(k(70), C, D, F)
     ll_ref, sel_ref_ = ops.gmm_align(x, dconst, dlin, dquad, A2, top_k=K)
-    tune = autotune_align(C, K, D, backend="cpu", frames=F)
+    tune = autotune_align(C, K, D, device_kind="cpu", frames=F)
     swept = sorted({(bf, dp) for _, bf, dp, _ in tune.candidates
                     if bf <= F})[:4]
     for bf, dp in swept:
@@ -194,6 +194,22 @@ def test_bw_stats(F, D, C):
     np.testing.assert_allclose(gS, wS, rtol=1e-5, atol=1e-4)
     # invariant: sum_c n_c == number of frames (posteriors sum to 1)
     np.testing.assert_allclose(jnp.sum(gn), F, rtol=1e-5)
+
+
+@pytest.mark.parametrize("N,D,C,K", [(64, 5, 16, 4), (200, 8, 32, 8)])
+def test_second_moments_grouped(N, D, C, K):
+    """The grouped-matmul second-order moments (the TPU path) == the
+    scatter-add reference; ids repeat within a frame and some components
+    receive no frame at all."""
+    x = jax.random.normal(k(50), (N, D))
+    sel = jax.random.randint(k(51), (N, K), 0, C - 2)
+    g = jax.random.uniform(k(52), (N, K))
+    want = ref.second_moments(x, g, sel, C)
+    with ops.use_pallas(True):
+        got = ops.second_moments(x, g, sel, C)
+    assert got.shape == (C, D * D)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(got)[C - 2:], 0.0)
 
 
 @pytest.mark.parametrize("U,C,R", [(32, 16, 12), (64, 64, 24)])
@@ -282,3 +298,30 @@ def test_selective_scan_kernel(B, T, di, ds, bt, bd):
     want = jnp.stack(ys, 1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
+
+
+def test_kernels_selected_by_platform(monkeypatch):
+    """The backend turns the kernels on (compiled, never interpreted);
+    ``use_pallas`` only steers tests."""
+    assert not ops._kernels_on()                 # CPU: jnp references
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    assert ops._kernels_on() and not ops._INTERPRET.get()
+    with ops.use_pallas(False):
+        assert not ops._kernels_on()
+    with ops.use_pallas(True):
+        assert ops._kernels_on() and ops._INTERPRET.get()
+
+
+@pytest.mark.parametrize("module,fn", [
+    ("gmm_rescore", "gmm_rescore"), ("gmm_loglik", "gmm_loglik"),
+    ("tvm_estep", "tvm_estep_l"), ("tvm_estep", "tvm_estep_a"),
+    ("gmm_align", "gmm_align"), ("bw_stats", "bw_stats"),
+    ("flash_attention", "flash_attention"),
+    ("selective_scan", "selective_scan"),
+])
+def test_kernels_compile_by_default(module, fn):
+    """No kernel defaults to interpret mode."""
+    import importlib
+    import inspect
+    f = getattr(importlib.import_module(f"repro.kernels.{module}"), fn)
+    assert inspect.signature(f).parameters["interpret"].default is False

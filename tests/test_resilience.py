@@ -488,7 +488,12 @@ def test_serving_chaos_kernel_degradation(served):
     utt = rng.standard_normal((20, cfg.feat_dim)).astype(np.float32)
     ex = IVectorExtractor.from_state(cfg, state, sv)
     ex._chaos_fail_modes = {"fused", "sparse"}
-    iv = ex.extract([utt])
+    with pytest.warns(RuntimeWarning, match="demoting to") as rec:
+        iv = ex.extract([utt])
+    # each demotion is announced, naming the mode and the exception
+    assert [str(w.message).split(";")[1].strip() for w in rec] == [
+        "demoting to 'sparse'", "demoting to 'dense'"]
+    assert "injected sparse-kernel failure" in str(rec[1].message)
     assert ex.mode == "dense" and ex.stats["degradations"] == 2
     dense = IVectorExtractor.from_state(
         cfg.with_overrides(rescore="dense"), state, sv)
@@ -502,7 +507,8 @@ def test_serving_chaos_all_modes_failing_raises(served):
     cfg, state, sv = served
     ex = IVectorExtractor.from_state(cfg, state, sv)
     ex._chaos_fail_modes = set(RESCORE_LADDER)
-    with pytest.raises(RuntimeError):
+    with pytest.raises(RuntimeError), pytest.warns(RuntimeWarning,
+                                                   match="demoting to"):
         ex.extract([np.zeros((8, cfg.feat_dim), np.float32)])
 
 
@@ -560,7 +566,8 @@ def test_serving_guardrail_health_probe(served):
     # reports ok on the demoted mode instead of failing at traffic time
     ex2 = IVectorExtractor.from_state(cfg, state, sv)
     ex2._chaos_fail_modes = {"fused"}
-    h2 = ex2.health_check()
+    with pytest.warns(RuntimeWarning, match="'fused' failed"):
+        h2 = ex2.health_check()
     assert h2["ok"] and h2["mode"] == "sparse" and h2["degradations"] == 1
 
 

@@ -112,7 +112,9 @@ def test_session_degradation_ladder():
     ladder and keeps serving (the batch extractor's contract)."""
     store = SessionStore(_extractor(rescore="sparse"), _scfg())
     store._chaos_fail_modes = {"sparse"}
-    iv, _ = store.update("s", _chunk(0))
+    with pytest.warns(RuntimeWarning, match="'sparse' failed .*demoting "
+                      "to 'dense'"):
+        iv, _ = store.update("s", _chunk(0))
     assert np.isfinite(iv).all()
     assert store._live.mode == "dense"
     assert store.stats["degradations"] == 1

@@ -94,3 +94,41 @@ def test_roofline_report_fields():
     for k in ("t_compute_s", "t_memory_s", "t_collective_s", "dominant",
               "useful_flops_ratio", "roofline_fraction"):
         assert k in row
+
+
+def test_roofline_table_keyed_by_device_kind():
+    """Peaks and tuning constants come from one table keyed by jax's
+    device_kind; an unknown device is an error, never a default."""
+    from repro.analysis import roofline
+    v5e = roofline.hardware("TPU v5 lite")
+    assert (v5e.peak_flops, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9,
+                                                          16e9)
+    assert roofline.local_hardware().name == jax.devices()[0].device_kind
+    with pytest.raises(ValueError, match="no roofline entry"):
+        roofline.hardware("TPU v9 imaginary")
+    with pytest.raises(ValueError, match="no roofline entry"):
+        roofline.autotune_align(2048, 20, 72, device_kind="gpu")
+
+
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX; otherwise the
+    cache goes to the fixed <repo>/.jax_cache."""
+    from pathlib import Path
+    from repro.launch import cache
+    was = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = cache.enable_compile_cache()
+        if from_env:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == was
+        else:
+            repo = Path(__file__).resolve().parents[1]
+            assert got == str(repo / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
