@@ -190,8 +190,8 @@ def serve_phase(cfg, state, utts, labels, seed: int, workdir: Path):
     t0 = time.perf_counter()
     again = ex.extract(utts)
     steady = time.perf_counter() - t0
-    log(f"time (chip run): extract {len(utts)} utterances: cold (compiles "
-        f"{ex.stats['compiles']} buckets) {cold:.3f}s, steady "
+    log(f"time (chip run): extract {len(utts)} utterances: cold ("
+        f"{ex.stats['compiles']} compiles) {cold:.3f}s, steady "
         f"{steady:.3f}s")
     check("serve i-vectors finite and non-zero", finite_nonzero(ivecs),
           f"shape {ivecs.shape}")

@@ -60,8 +60,9 @@ def floor_renormalise(post, floor: float) -> jax.Array:
 
 def preselect(diag: U.DiagGMM, x, top_k: int):
     """Phase 1: diag-UBM scores [F, C] + top-K component ids [F, K]."""
-    diag_ll = U.diag_loglik(diag, x)
-    _, sel = jax.lax.top_k(diag_ll, top_k)
+    with jax.named_scope("ivec_preselect"):
+        diag_ll = U.diag_loglik(diag, x)
+        _, sel = jax.lax.top_k(diag_ll, top_k)
     return diag_ll, sel
 
 
@@ -83,19 +84,20 @@ def rescore_selected(x, sel, full, diag_ll, *, precomp=None,
     rounding — 'dense' stays the reference fallback of the
     fused→sparse→dense ladder (DESIGN.md §12).
     """
-    if full is None and precomp is None:
-        return jnp.take_along_axis(diag_ll, sel, axis=1)
-    if rescore == "sparse":
-        return U.full_rescore(full, x, sel, precomp=precomp,
-                              pack=rescore_pack)
-    if rescore == "fused":
-        return U.full_rescore_fused(full, x, sel, precomp=precomp,
-                                    pack=align_pack)
-    if rescore != "dense":
-        raise ValueError(
-            f"rescore must be 'dense', 'sparse' or 'fused': {rescore}")
-    ll = U.full_loglik(full, x, precomp=precomp)            # [F, C]
-    return jnp.take_along_axis(ll, sel, axis=1)
+    with jax.named_scope("ivec_rescore"):
+        if full is None and precomp is None:
+            return jnp.take_along_axis(diag_ll, sel, axis=1)
+        if rescore == "sparse":
+            return U.full_rescore(full, x, sel, precomp=precomp,
+                                  pack=rescore_pack)
+        if rescore == "fused":
+            return U.full_rescore_fused(full, x, sel, precomp=precomp,
+                                        pack=align_pack)
+        if rescore != "dense":
+            raise ValueError(
+                f"rescore must be 'dense', 'sparse' or 'fused': {rescore}")
+        ll = U.full_loglik(full, x, precomp=precomp)        # [F, C]
+        return jnp.take_along_axis(ll, sel, axis=1)
 
 
 def finalise_posteriors(sel_ll, floor: float, mask=None):
@@ -107,15 +109,16 @@ def finalise_posteriors(sel_ll, floor: float, mask=None):
     `engine._align_sharded` (where ``sel_ll`` arrives replicated after the
     masked pmax), so the two paths are the same code, not two copies.
     """
-    lse = jax.scipy.special.logsumexp(sel_ll, axis=1)      # [F]
-    post = floor_renormalise(jnp.exp(sel_ll - lse[:, None]), floor)
-    if mask is not None:
-        # where, not multiply: garbage padding frames can produce NaN/inf
-        # posteriors (overflowing logliks), and NaN * 0 == NaN
-        valid = mask.astype(bool)
-        post = jnp.where(valid[:, None], post, 0.0)
-        lse = jnp.where(valid, lse, 0.0)
-    return post.astype(f32), lse.astype(f32)
+    with jax.named_scope("ivec_rescore"):
+        lse = jax.scipy.special.logsumexp(sel_ll, axis=1)  # [F]
+        post = floor_renormalise(jnp.exp(sel_ll - lse[:, None]), floor)
+        if mask is not None:
+            # where, not multiply: garbage padding frames can produce
+            # NaN/inf posteriors (overflowing logliks), and NaN * 0 == NaN
+            valid = mask.astype(bool)
+            post = jnp.where(valid[:, None], post, 0.0)
+            lse = jnp.where(valid, lse, 0.0)
+        return post.astype(f32), lse.astype(f32)
 
 
 def align_frames(x, full, diag: U.DiagGMM, *, top_k: int = 20,

@@ -121,9 +121,11 @@ class UBMPack(NamedTuple):
 
 
 def pack_ubm(ubm: U.FullGMM) -> UBMPack:
-    pre = U.full_precisions(ubm)
-    return UBMPack(ubm, ubm.to_diag(), pre, U.rescore_pack(pre),
-                   U.align_pack(pre))
+    # the rescoring precompute (the diag GMM is a slice of the covariances)
+    with jax.named_scope("ivec_rescore"):
+        pre = U.full_precisions(ubm)
+        return UBMPack(ubm, ubm.to_diag(), pre, U.rescore_pack(pre),
+                       U.align_pack(pre))
 
 
 def pack_diag(gmm: U.DiagGMM) -> UBMPack:
@@ -171,39 +173,41 @@ def _align_sharded(spec: EngineSpec, pack: UBMPack, x, m, axis: str):
     r = jax.lax.axis_index(axis)
     C_loc = pack.diag.means.shape[0]
     K = spec.top_k
-    dll = U.diag_loglik(pack.diag, x)                 # [f, C_loc]
-    k_loc = min(K, C_loc)
-    lv, li = jax.lax.top_k(dll, k_loc)
-    gi = li + r * C_loc                               # global ids
-    lv_all = jax.lax.all_gather(lv, axis, axis=1, tiled=True)
-    gi_all = jax.lax.all_gather(gi, axis, axis=1, tiled=True)
-    sv, sp = jax.lax.top_k(lv_all, K)
-    sel = jnp.take_along_axis(gi_all, sp, axis=1)     # [f, K] global ids
-    own = (sel // C_loc) == r
-    loc = jnp.where(own, sel % C_loc, 0)
-    if pack.pre is None:
-        # diag phase: the preselection scores ARE the selected-set scores
-        vals = jnp.take_along_axis(dll, loc, axis=1)
-    elif spec.rescore == "sparse":
-        # gather-and-rescore only the selected slots against the local
-        # C-block — [f, C_loc] full-cov scores never materialise
-        fc, fl, fP = pack.pre
-        vals = ops.gmm_rescore(x, loc, fc, fl.T,
-                               fP.reshape(fP.shape[0], -1),
-                               pack=pack.rescore_A)
-    elif spec.rescore == "fused":
-        # fused packed-GEMM rescore of the selected slots against the
-        # local C-block's align_A rows ([C_loc, E2] — shards uniformly
-        # over 'model' like every other pack leaf)
-        vals = ops.gmm_rescore_fused(x, loc, pack.align_A)
-    else:
-        fc, fl, fP = pack.pre
-        fll = ops.gmm_loglik(x, fc, fl.T, fP.reshape(fP.shape[0], -1))
-        vals = jnp.take_along_axis(fll, loc, axis=1)
-    vals = jnp.where(own, vals, -jnp.inf)
-    sel_ll = jax.lax.pmax(vals, axis)                 # [f, K] replicated
-    post, lse = AL.finalise_posteriors(sel_ll, spec.floor, m)
-    return jnp.where(own, post, 0.0), loc, lse
+    with jax.named_scope("ivec_preselect"):
+        dll = U.diag_loglik(pack.diag, x)             # [f, C_loc]
+        k_loc = min(K, C_loc)
+        lv, li = jax.lax.top_k(dll, k_loc)
+        gi = li + r * C_loc                           # global ids
+        lv_all = jax.lax.all_gather(lv, axis, axis=1, tiled=True)
+        gi_all = jax.lax.all_gather(gi, axis, axis=1, tiled=True)
+        sv, sp = jax.lax.top_k(lv_all, K)
+        sel = jnp.take_along_axis(gi_all, sp, axis=1)  # [f, K] global ids
+    with jax.named_scope("ivec_rescore"):
+        own = (sel // C_loc) == r
+        loc = jnp.where(own, sel % C_loc, 0)
+        if pack.pre is None:
+            # diag phase: the preselection scores ARE the selected-set scores
+            vals = jnp.take_along_axis(dll, loc, axis=1)
+        elif spec.rescore == "sparse":
+            # gather-and-rescore only the selected slots against the local
+            # C-block — [f, C_loc] full-cov scores never materialise
+            fc, fl, fP = pack.pre
+            vals = ops.gmm_rescore(x, loc, fc, fl.T,
+                                   fP.reshape(fP.shape[0], -1),
+                                   pack=pack.rescore_A)
+        elif spec.rescore == "fused":
+            # fused packed-GEMM rescore of the selected slots against the
+            # local C-block's align_A rows ([C_loc, E2] — shards uniformly
+            # over 'model' like every other pack leaf)
+            vals = ops.gmm_rescore_fused(x, loc, pack.align_A)
+        else:
+            fc, fl, fP = pack.pre
+            fll = ops.gmm_loglik(x, fc, fl.T, fP.reshape(fP.shape[0], -1))
+            vals = jnp.take_along_axis(fll, loc, axis=1)
+        vals = jnp.where(own, vals, -jnp.inf)
+        sel_ll = jax.lax.pmax(vals, axis)             # [f, K] replicated
+        post, lse = AL.finalise_posteriors(sel_ll, spec.floor, m)
+        return jnp.where(own, post, 0.0), loc, lse
 
 
 def chunk_body(spec: EngineSpec, pack: UBMPack, feats_c,
@@ -304,12 +308,14 @@ class TotalsAccum:
 
     def update(self, carry, chunk: ChunkStats):
         n, f, S, ll, fr = carry
-        if chunk.S is not None:
-            # pinned: XLA would fold a scattered S into the carry and
-            # reorder the sums against the mesh path's per-chunk S
-            S = S + pin(chunk.S)
-        return (n + jnp.sum(chunk.n, axis=0), f + jnp.sum(chunk.f, axis=0),
-                S, ll + chunk.loglik, fr + chunk.frames)
+        with jax.named_scope("ivec_moments"):
+            if chunk.S is not None:
+                # pinned: XLA would fold a scattered S into the carry and
+                # reorder the sums against the mesh path's per-chunk S
+                S = S + pin(chunk.S)
+            return (n + jnp.sum(chunk.n, axis=0),
+                    f + jnp.sum(chunk.f, axis=0),
+                    S, ll + chunk.loglik, fr + chunk.frames)
 
     def finalize(self, carry) -> UBMStats:
         n, f, S, ll, fr = carry
@@ -368,13 +374,14 @@ class TVMAccum:
 
     def update(self, carry, chunk: ChunkStats):
         n, f = chunk.n, chunk.f
-        if self.center_means is not None:
-            st = ST.center(ST.BWStats(n, f, None), self.center_means)
-            n, f = st.n, st.f
-        return TV.merge_accums(
-            carry, TV.em_accumulate(self.model, self.pre, n, f,
-                                    estep_dtype=self.estep_dtype,
-                                    axis=self.axis))
+        with jax.named_scope("ivec_estep"):
+            if self.center_means is not None:
+                st = ST.center(ST.BWStats(n, f, None), self.center_means)
+                n, f = st.n, st.f
+            return TV.merge_accums(
+                carry, TV.em_accumulate(self.model, self.pre, n, f,
+                                        estep_dtype=self.estep_dtype,
+                                        axis=self.axis))
 
     def finalize(self, carry) -> TV.EMAccum:
         return carry

@@ -44,25 +44,26 @@ def scatter_accumulate(x, values, indices, utt_ids, n_utts: int, C: int,
     """
     N, D = x.shape
     K = values.shape[1]
-    if mask is not None:
-        # where, not multiply: NaN/inf in garbage padding frames must not
-        # survive masking (NaN * 0 == NaN)
-        valid = mask.astype(bool)[:, None]
-        values = jnp.where(valid, values, 0.0)
-        x = jnp.where(valid, x, 0.0)
-    rows_u = jnp.repeat(utt_ids, K)            # [N*K]
-    rows_c = indices.reshape(-1)               # [N*K]
-    n = jnp.zeros((n_utts, C), f32).at[rows_u, rows_c].add(
-        values.reshape(-1))
-    xw = (values[:, :, None] * x[:, None, :]).reshape(N * K, D)
-    f = jnp.zeros((n_utts, C, D), f32).at[rows_u, rows_c].add(xw)
-    S = None
-    if second_order == "diag":
-        sw = (values[:, :, None] * (x * x)[:, None, :]).reshape(N * K, D)
-        S = jnp.zeros((C, D), f32).at[rows_c].add(sw)
-    elif second_order == "full":
-        # a grouped contraction where kernels run (kernels/ops.py)
-        S = ops.second_moments(x, values, indices, C)
+    with jax.named_scope("ivec_moments"):
+        if mask is not None:
+            # where, not multiply: NaN/inf in garbage padding frames must
+            # not survive masking (NaN * 0 == NaN)
+            valid = mask.astype(bool)[:, None]
+            values = jnp.where(valid, values, 0.0)
+            x = jnp.where(valid, x, 0.0)
+        rows_u = jnp.repeat(utt_ids, K)            # [N*K]
+        rows_c = indices.reshape(-1)               # [N*K]
+        n = jnp.zeros((n_utts, C), f32).at[rows_u, rows_c].add(
+            values.reshape(-1))
+        xw = (values[:, :, None] * x[:, None, :]).reshape(N * K, D)
+        f = jnp.zeros((n_utts, C, D), f32).at[rows_u, rows_c].add(xw)
+        S = None
+        if second_order == "diag":
+            sw = (values[:, :, None] * (x * x)[:, None, :]).reshape(N * K, D)
+            S = jnp.zeros((C, D), f32).at[rows_c].add(sw)
+        elif second_order == "full":
+            # a grouped contraction where kernels run (kernels/ops.py)
+            S = ops.second_moments(x, values, indices, C)
     return n, f, S
 
 

@@ -87,14 +87,16 @@ def precompute(model: TVModel, estep: str = "dense") -> Precomp:
     """
     if estep not in ("dense", "packed"):
         raise ValueError(f"estep must be 'dense'|'packed', got {estep!r}")
-    chol = jnp.linalg.cholesky(model.Sigma)
-    Pj = jax.scipy.linalg.cho_solve((chol, True), model.T)
-    Uc = jnp.einsum("cdr,cds->crs", model.T, Pj, precision=HI)
-    # exact symmetry before packing (fp round-off from the solve)
-    Uc = 0.5 * (Uc + Uc.transpose(0, 2, 1))
-    if estep == "packed":
-        return Precomp(ops.pack_symmetric(Uc).astype(f32), Pj.astype(f32))
-    return Precomp(Uc.astype(f32), Pj.astype(f32))
+    with jax.named_scope("ivec_estep"):
+        chol = jnp.linalg.cholesky(model.Sigma)
+        Pj = jax.scipy.linalg.cho_solve((chol, True), model.T)
+        Uc = jnp.einsum("cdr,cds->crs", model.T, Pj, precision=HI)
+        # exact symmetry before packing (fp round-off from the solve)
+        Uc = 0.5 * (Uc + Uc.transpose(0, 2, 1))
+        if estep == "packed":
+            return Precomp(ops.pack_symmetric(Uc).astype(f32),
+                           Pj.astype(f32))
+        return Precomp(Uc.astype(f32), Pj.astype(f32))
 
 
 def posterior(model: TVModel, pre: Precomp, n, f, mean_only: bool = False,
@@ -211,32 +213,33 @@ def em_accumulate(model: TVModel, pre: Precomp, n, f,
     h/H/n_utts are replicated — exactly the packing the engine's exit
     psum expects (DESIGN.md §11).
     """
-    phi, Phi = posterior(model, pre, n, f, estep_dtype=estep_dtype,
-                         axis=axis)
-    if pre.packed:
-        # assemble Phi + φφᵀ DIRECTLY in packed form: pack Phi once and
-        # add the packed outer product φ_{i0} φ_{i1} — the dense [U, R, R]
-        # second moment never exists (DESIGN.md §12)
-        iu = jnp.triu_indices(model.rank)
-        i0, i1 = iu[0].astype(jnp.int32), iu[1].astype(jnp.int32)
-        PPp = (ops.pack_symmetric(Phi)
-               + jnp.take(phi, i0, axis=1) * jnp.take(phi, i1, axis=1))
-        A = ops.tvm_estep_a(n, PPp, dtype=estep_dtype)         # [C, P]
-    else:
-        PP = Phi + phi[:, :, None] * phi[:, None, :]
-        # f32 accumulation pinned (rule NUM001): n/f may arrive bf16
-        # under the mixed-precision E-step
-        A = jnp.einsum("uc,urs->crs", n, PP, precision=HI,
+    with jax.named_scope("ivec_estep"):
+        phi, Phi = posterior(model, pre, n, f, estep_dtype=estep_dtype,
+                             axis=axis)
+        if pre.packed:
+            # assemble Phi + φφᵀ DIRECTLY in packed form: pack Phi once and
+            # add the packed outer product φ_{i0} φ_{i1} — the dense [U, R, R]
+            # second moment never exists (DESIGN.md §12)
+            iu = jnp.triu_indices(model.rank)
+            i0, i1 = iu[0].astype(jnp.int32), iu[1].astype(jnp.int32)
+            PPp = (ops.pack_symmetric(Phi)
+                   + jnp.take(phi, i0, axis=1) * jnp.take(phi, i1, axis=1))
+            A = ops.tvm_estep_a(n, PPp, dtype=estep_dtype)         # [C, P]
+        else:
+            PP = Phi + phi[:, :, None] * phi[:, None, :]
+            # f32 accumulation pinned (rule NUM001): n/f may arrive bf16
+            # under the mixed-precision E-step
+            A = jnp.einsum("uc,urs->crs", n, PP, precision=HI,
+                           preferred_element_type=f32)
+        B = jnp.einsum("ucd,ur->cdr", f, phi, precision=HI,
                        preferred_element_type=f32)
-    B = jnp.einsum("ucd,ur->cdr", f, phi, precision=HI,
-                   preferred_element_type=f32)
-    dphi = phi - model.prior[None]
-    H = jnp.sum(Phi, axis=0) + jnp.einsum("ur,us->rs", dphi, dphi,
-                                          precision=HI,
-                                          preferred_element_type=f32)
-    return EMAccum(A=A, B=B, h=jnp.sum(dphi, axis=0), H=H,
-                   n_tot=jnp.sum(n, axis=0),
-                   n_utts=jnp.asarray(n.shape[0], f32))
+        dphi = phi - model.prior[None]
+        H = jnp.sum(Phi, axis=0) + jnp.einsum("ur,us->rs", dphi, dphi,
+                                              precision=HI,
+                                              preferred_element_type=f32)
+        return EMAccum(A=A, B=B, h=jnp.sum(dphi, axis=0), H=H,
+                       n_tot=jnp.sum(n, axis=0),
+                       n_utts=jnp.asarray(n.shape[0], f32))
 
 
 def mean_phi(model: TVModel, acc: EMAccum) -> jax.Array:
@@ -259,26 +262,28 @@ def em_accumulate_scan(model: TVModel, pre: Precomp, n, f,
     arbitrary batch sizes keep the bounded [chunk, R, R] footprint (falling
     back to the unchunked path would be exactly the memory blow-up the
     chunking exists to avoid)."""
-    U_, C = n.shape
-    chunk = min(chunk, U_)
-    g = U_ // chunk
-    rem = U_ % chunk
-    R, D = model.rank, model.T.shape[1]
+    with jax.named_scope("ivec_estep"):
+        U_, C = n.shape
+        chunk = min(chunk, U_)
+        g = U_ // chunk
+        rem = U_ % chunk
+        R, D = model.rank, model.T.shape[1]
 
-    def body(carry, inp):
-        nc, fc = inp
-        acc = em_accumulate(model, pre, nc, fc, estep_dtype=estep_dtype)
-        return merge_accums(carry, acc), None
+        def body(carry, inp):
+            nc, fc = inp
+            acc = em_accumulate(model, pre, nc, fc, estep_dtype=estep_dtype)
+            return merge_accums(carry, acc), None
 
-    zero = EMAccum.zeros(C, D, R, estep="packed" if pre.packed else "dense")
-    nr = n[:g * chunk].reshape(g, chunk, C)
-    fr = f[:g * chunk].reshape(g, chunk, C, D)
-    acc, _ = jax.lax.scan(body, zero, (nr, fr))
-    if rem:
-        acc = merge_accums(
-            acc, em_accumulate(model, pre, n[g * chunk:], f[g * chunk:],
-                               estep_dtype=estep_dtype))
-    return acc
+        zero = EMAccum.zeros(C, D, R,
+                             estep="packed" if pre.packed else "dense")
+        nr = n[:g * chunk].reshape(g, chunk, C)
+        fr = f[:g * chunk].reshape(g, chunk, C, D)
+        acc, _ = jax.lax.scan(body, zero, (nr, fr))
+        if rem:
+            acc = merge_accums(
+                acc, em_accumulate(model, pre, n[g * chunk:], f[g * chunk:],
+                                   estep_dtype=estep_dtype))
+        return acc
 
 
 # ---------------------------------------------------------------------------
@@ -292,27 +297,28 @@ def m_step(model: TVModel, acc: EMAccum, S_tot: Optional[jax.Array],
 
     A packed accumulator ([C, P]) is unpacked here — the batched-solve
     boundary — exactly as L unpacks at the Cholesky boundary."""
-    R = model.rank
-    A = ops.unpack_symmetric(acc.A, R) if acc.A.ndim == 2 else acc.A
-    # T_c = B_c A_c^{-1}; solve A_c^T X^T = B_c^T  (A symmetric)
-    A_reg = A + 1e-6 * jnp.eye(R, dtype=f32)[None]
-    T_new = jnp.linalg.solve(A_reg, acc.B.transpose(0, 2, 1)) \
-        .transpose(0, 2, 1)
-    Sigma = model.Sigma
-    if update_sigma and S_tot is not None:
-        n_safe = jnp.maximum(acc.n_tot, 1e-6)[:, None, None]
-        TB = jnp.einsum("cdr,cer->cde", T_new, acc.B, precision=HI)
-        resid = S_tot - 0.5 * (TB + TB.transpose(0, 2, 1))
-        D = resid.shape[1]
-        # Kaldi's variance floor: a fraction of the occupancy-weighted
-        # average residual covariance. A component seen in fewer frames
-        # than D has a rank-deficient estimate; unfloored, its precision
-        # explodes in the next E-step and the iterations diverge to NaN.
-        floor = (VAR_FLOOR_FACTOR * jnp.sum(resid, axis=0)
-                 / jnp.maximum(jnp.sum(acc.n_tot), 1e-6))
-        floor = 0.5 * (floor + floor.T) + COV_FLOOR * jnp.eye(D)
-        Sigma = floor_covariances(resid / n_safe, floor)
-    return replace(model, T=T_new.astype(f32), Sigma=Sigma.astype(f32))
+    with jax.named_scope("ivec_mstep"):
+        R = model.rank
+        A = ops.unpack_symmetric(acc.A, R) if acc.A.ndim == 2 else acc.A
+        # T_c = B_c A_c^{-1}; solve A_c^T X^T = B_c^T  (A symmetric)
+        A_reg = A + 1e-6 * jnp.eye(R, dtype=f32)[None]
+        T_new = jnp.linalg.solve(A_reg, acc.B.transpose(0, 2, 1)) \
+            .transpose(0, 2, 1)
+        Sigma = model.Sigma
+        if update_sigma and S_tot is not None:
+            n_safe = jnp.maximum(acc.n_tot, 1e-6)[:, None, None]
+            TB = jnp.einsum("cdr,cer->cde", T_new, acc.B, precision=HI)
+            resid = S_tot - 0.5 * (TB + TB.transpose(0, 2, 1))
+            D = resid.shape[1]
+            # Kaldi's variance floor: a fraction of the occupancy-weighted
+            # average residual covariance. A component seen in fewer frames
+            # than D has a rank-deficient estimate; unfloored, its precision
+            # explodes in the next E-step and the iterations diverge to NaN.
+            floor = (VAR_FLOOR_FACTOR * jnp.sum(resid, axis=0)
+                     / jnp.maximum(jnp.sum(acc.n_tot), 1e-6))
+            floor = 0.5 * (floor + floor.T) + COV_FLOOR * jnp.eye(D)
+            Sigma = floor_covariances(resid / n_safe, floor)
+        return replace(model, T=T_new.astype(f32), Sigma=Sigma.astype(f32))
 
 
 def floor_covariances(covs, floor):
@@ -320,15 +326,16 @@ def floor_covariances(covs, floor):
     [D, D] (SPD) in the PSD order (Kaldi's ``SpMatrix::ApplyFloor``):
     whiten by the floor's Cholesky factor L, clamp the eigenvalues of
     L⁻¹ Σ L⁻ᵀ at 1, and map back."""
-    D = floor.shape[0]
-    L = jnp.linalg.cholesky(floor)
-    Li = jax.scipy.linalg.solve_triangular(L, jnp.eye(D, dtype=f32),
-                                           lower=True)
-    M = jnp.einsum("ij,cjk,lk->cil", Li, covs, Li, precision=HI)
-    lam, Q = jnp.linalg.eigh(0.5 * (M + M.transpose(0, 2, 1)))
-    M = jnp.einsum("cir,cr,cjr->cij", Q, jnp.maximum(lam, 1.0), Q,
-                   precision=HI)
-    return jnp.einsum("ij,cjk,lk->cil", L, M, L, precision=HI)
+    with jax.named_scope("ivec_sigma_floor"):
+        D = floor.shape[0]
+        L = jnp.linalg.cholesky(floor)
+        Li = jax.scipy.linalg.solve_triangular(L, jnp.eye(D, dtype=f32),
+                                               lower=True)
+        M = jnp.einsum("ij,cjk,lk->cil", Li, covs, Li, precision=HI)
+        lam, Q = jnp.linalg.eigh(0.5 * (M + M.transpose(0, 2, 1)))
+        M = jnp.einsum("cir,cr,cjr->cij", Q, jnp.maximum(lam, 1.0), Q,
+                       precision=HI)
+        return jnp.einsum("ij,cjk,lk->cil", L, M, L, precision=HI)
 
 
 # ---------------------------------------------------------------------------
@@ -338,42 +345,45 @@ def floor_covariances(covs, floor):
 
 def min_divergence(model: TVModel, acc: EMAccum,
                    update_means: bool = False) -> TVModel:
-    nu = jnp.maximum(acc.n_utts, 1.0)
-    dh = acc.h / nu                    # mean of phi - prior
-    G = acc.H / nu - dh[:, None] * dh[None, :]
-    h = dh + model.prior
-    R = model.rank
-    G = G + 1e-8 * jnp.eye(R, dtype=f32)
-    lam, Q = jnp.linalg.eigh(G)
-    lam = jnp.maximum(lam, 1e-10)
-    P1 = (Q * (lam ** -0.5)[None, :]).T            # Λ^{-1/2} Q^T
-    P1_inv = Q * (lam ** 0.5)[None, :]             # Q Λ^{1/2}
+    with jax.named_scope("ivec_mstep"), \
+            jax.named_scope("ivec_min_divergence"):
+        nu = jnp.maximum(acc.n_utts, 1.0)
+        dh = acc.h / nu                    # mean of phi - prior
+        G = acc.H / nu - dh[:, None] * dh[None, :]
+        h = dh + model.prior
+        R = model.rank
+        G = G + 1e-8 * jnp.eye(R, dtype=f32)
+        lam, Q = jnp.linalg.eigh(G)
+        lam = jnp.maximum(lam, 1e-10)
+        P1 = (Q * (lam ** -0.5)[None, :]).T            # Λ^{-1/2} Q^T
+        P1_inv = Q * (lam ** 0.5)[None, :]             # Q Λ^{1/2}
 
-    if model.formulation == "standard":
-        T_new = jnp.einsum("cdr,rs->cds", model.T, P1_inv, precision=HI)
-        means = model.means
-        if update_means:
-            # paper §5: m_c^upd = m_c + T_c h  (old T)
-            means = means + jnp.einsum("cdr,r->cd", model.T, h,
-                                       precision=HI)
-        return replace(model, T=T_new.astype(f32), means=means)
+        if model.formulation == "standard":
+            T_new = jnp.einsum("cdr,rs->cds", model.T, P1_inv, precision=HI)
+            means = model.means
+            if update_means:
+                # paper §5: m_c^upd = m_c + T_c h  (old T)
+                means = means + jnp.einsum("cdr,r->cd", model.T, h,
+                                           precision=HI)
+            return replace(model, T=T_new.astype(f32), means=means)
 
-    # augmented: additionally require P2 P1 h = b e1 (Householder, eqs 8-11)
-    p1h = jnp.dot(P1, h, precision=HI)
-    norm = jnp.linalg.norm(p1h)
-    h_t = p1h / jnp.maximum(norm, 1e-10)
-    e1 = jnp.zeros((R,), f32).at[0].set(1.0)
-    denom = jnp.maximum(2.0 * (1.0 - h_t[0]), 1e-10)
-    alpha = denom ** -0.5
-    a = alpha * h_t - alpha * e1
-    # degenerate case: h already along e1 -> P2 = I
-    degenerate = (1.0 - h_t[0]) < 1e-8
-    P2 = jnp.where(degenerate, jnp.eye(R, dtype=f32),
-                   jnp.eye(R, dtype=f32) - 2.0 * a[:, None] * a[None, :])
-    # T <- T P1^{-1} P2^{-1}; P2 is a reflection: P2^{-1} = P2
-    T_new = jnp.einsum("cdr,rs,st->cdt", model.T, P1_inv, P2, precision=HI)
-    prior = jnp.where(degenerate, p1h, jnp.dot(P2, p1h, precision=HI))
-    return replace(model, T=T_new.astype(f32), prior=prior.astype(f32))
+        # augmented: additionally require P2 P1 h = b e1 (Householder,
+        # eqs 8-11)
+        p1h = jnp.dot(P1, h, precision=HI)
+        norm = jnp.linalg.norm(p1h)
+        h_t = p1h / jnp.maximum(norm, 1e-10)
+        e1 = jnp.zeros((R,), f32).at[0].set(1.0)
+        denom = jnp.maximum(2.0 * (1.0 - h_t[0]), 1e-10)
+        alpha = denom ** -0.5
+        a = alpha * h_t - alpha * e1
+        # degenerate case: h already along e1 -> P2 = I
+        degenerate = (1.0 - h_t[0]) < 1e-8
+        P2 = jnp.where(degenerate, jnp.eye(R, dtype=f32),
+                       jnp.eye(R, dtype=f32) - 2.0 * a[:, None] * a[None, :])
+        # T <- T P1^{-1} P2^{-1}; P2 is a reflection: P2^{-1} = P2
+        T_new = jnp.einsum("cdr,rs,st->cdt", model.T, P1_inv, P2, precision=HI)
+        prior = jnp.where(degenerate, p1h, jnp.dot(P2, p1h, precision=HI))
+        return replace(model, T=T_new.astype(f32), prior=prior.astype(f32))
 
 
 # ---------------------------------------------------------------------------
@@ -396,6 +406,7 @@ def extract_ivectors(model: TVModel, pre: Precomp, n, f,
     posterior path: the [U, R, R] covariance (an identity-RHS solve that
     serving used to compute and discard) is never formed — R× fewer
     triangular solves per extraction."""
-    phi, _ = posterior(model, pre, n, f, mean_only=True,
-                       estep_dtype=estep_dtype)
-    return phi - model.prior[None]
+    with jax.named_scope("ivec_estep"):
+        phi, _ = posterior(model, pre, n, f, mean_only=True,
+                           estep_dtype=estep_dtype)
+        return phi - model.prior[None]

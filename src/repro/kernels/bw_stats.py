@@ -69,4 +69,5 @@ def bw_stats(gamma, x, *, block_f: int = 256, block_c: int = 128,
             jax.ShapeDtypeStruct((C, D * D), f32),
         ],
         interpret=interpret,
+        name="bw_stats",
     )(gamma, x)

@@ -189,4 +189,5 @@ def gmm_align(x, dconst, dlin, dquad, sexp, A2, *, top_k: int,
             pltpu.SemaphoreType.DMA((depth,)),
         ],
         interpret=interpret,
+        name="gmm_align",
     )(x, dconst, dlin, dquad, sexp, A2)

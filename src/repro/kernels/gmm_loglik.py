@@ -62,4 +62,5 @@ def gmm_loglik(x, const, lin, P_flat, *, block_f: int = BLOCK_F,
         out_specs=pl.BlockSpec((bf, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((F, C), f32),
         interpret=interpret,
+        name="gmm_loglik",
     )(x, const, lin, P_flat)

@@ -132,4 +132,5 @@ def gmm_rescore(xe, sel, A, *, block_f: int = BLOCK_F,
             pltpu.SemaphoreType.DMA((depth,)),
         ],
         interpret=interpret,
+        name="gmm_rescore",
     )(ids_sorted.reshape(T, 1, n), order.reshape(T, 1, n), xe, A)

@@ -60,8 +60,10 @@ def _matmul_kernel(a_ref, b_ref, out_ref, *, widen: bool):
         out_ref[...] += part
 
 
-def _packed_matmul(a, b, *, bm: int, bp: int, bk: int, interpret: bool):
-    """a: [M, K]; b: [K, P] -> [M, P] f32, reduction accumulated over K."""
+def _packed_matmul(a, b, *, bm: int, bp: int, bk: int, interpret: bool,
+                   name: str):
+    """a: [M, K]; b: [K, P] -> [M, P] f32, reduction accumulated over K;
+    ``name`` names the kernel (its calling wrapper's name)."""
     M, K = a.shape
     P = b.shape[1]
     bm, bp, bk = min(bm, M), min(bp, P), min(bk, K)
@@ -77,6 +79,7 @@ def _packed_matmul(a, b, *, bm: int, bp: int, bk: int, interpret: bool):
         out_specs=pl.BlockSpec((bm, bp), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, P), f32),
         interpret=interpret,
+        name=name,
     )(a, b)
 
 
@@ -91,7 +94,7 @@ def tvm_estep_l(n, U_packed, *, block_u: int = BLOCK_U,
     unpacking at the Cholesky boundary (`core/tvm.posterior`).
     """
     return _packed_matmul(n, U_packed, bm=block_u, bp=block_p, bk=block_c,
-                          interpret=interpret)
+                          interpret=interpret, name="tvm_estep_l")
 
 
 @functools.partial(jax.jit, static_argnames=("block_u", "block_p", "block_c",
@@ -105,4 +108,4 @@ def tvm_estep_a(n, PP_packed, *, block_u: int = BLOCK_U,
     Phi_u + φ_u φ_uᵀ; the result is the packed M-step operand A_c.
     """
     return _packed_matmul(n.T, PP_packed, bm=block_c, bp=block_p,
-                          bk=block_u, interpret=interpret)
+                          bk=block_u, interpret=interpret, name="tvm_estep_a")

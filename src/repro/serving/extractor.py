@@ -53,6 +53,8 @@ from repro.core import tvm as TV
 from repro.core import ubm as U
 
 f32 = jnp.float32
+# the host span of extract's packing (profiler trace event name)
+PACK_SPAN = "ivec.extract.pack"
 
 
 def bucket_cap(min_bucket: int, max_bucket: int) -> int:
@@ -134,7 +136,8 @@ class IVectorExtractor:
         # chaos hook (tests): modes whose device call raises, simulating
         # a kernel failure
         self._chaos_fail_modes: set = set()
-        self._seen_buckets: set = set()
+        self._called: set = set()     # (mode, bucket) pairs run so far
+        # "compiles" counts the first call of each (mode, bucket) pair
         self.stats = {"requests": 0, "batches": 0, "compiles": 0,
                       "real_frames": 0, "padded_frames": 0, "truncated": 0,
                       "empty": 0, "nonfinite_frames": 0,
@@ -165,7 +168,7 @@ class IVectorExtractor:
         return bucket_for(n_frames, self.serving.min_bucket, self._cap)
 
     def buckets(self) -> List[int]:
-        return sorted(self._seen_buckets)
+        return sorted({b for _, b in self._called})
 
     # -- the jitted per-bucket extraction -----------------------------------
 
@@ -214,6 +217,9 @@ class IVectorExtractor:
                         f"injected {mode}-kernel failure (chaos)")
                 if mode not in self._fns:
                     self._fns[mode] = self._make_fn(mode)
+                if (mode, feats.shape[1]) not in self._called:
+                    self._called.add((mode, feats.shape[1]))
+                    self.stats["compiles"] += 1
                 return np.asarray(self._fns[mode](
                     self._pack, self.model, self._tv_pre, feats, mask))
             except Exception as e:
@@ -263,30 +269,33 @@ class IVectorExtractor:
         R = self.model.rank
         B = self.serving.max_batch
         utts, valids, infos = [], [], []
-        for raw in utterances:
-            u, valid, info = self._validate(np.asarray(raw, np.float32), D)
-            utts.append(u)
-            valids.append(valid)
-            infos.append(info)
-        groups: Dict[int, List[int]] = {}
-        for i, info in enumerate(infos):
-            groups.setdefault(info.bucket, []).append(i)
+        # host packing: validation and bucketing here, each batch's padded
+        # arrays below
+        with jax.profiler.TraceAnnotation(PACK_SPAN, rows=len(utterances)):
+            for raw in utterances:
+                u, valid, info = self._validate(np.asarray(raw, np.float32),
+                                                D)
+                utts.append(u)
+                valids.append(valid)
+                infos.append(info)
+            groups: Dict[int, List[int]] = {}
+            for i, info in enumerate(infos):
+                groups.setdefault(info.bucket, []).append(i)
         out = np.zeros((len(utts), R), np.float32)
         for bucket in sorted(groups):
-            if bucket not in self._seen_buckets:
-                self._seen_buckets.add(bucket)
-                self.stats["compiles"] += 1
             idxs = groups[bucket]
             for s in range(0, len(idxs), B):
                 chunk = idxs[s:s + B]
-                feats = np.zeros((B, bucket, D), np.float32)
-                mask = np.zeros((B, bucket), np.float32)
-                for j, i in enumerate(chunk):
-                    n = min(utts[i].shape[0], bucket)
-                    feats[j, :n] = utts[i][:n]
-                    mask[j, :n] = valids[i][:n].astype(np.float32)
-                    self.stats["real_frames"] += n
-                    self.stats["padded_frames"] += bucket - n
+                with jax.profiler.TraceAnnotation(PACK_SPAN, bucket=bucket,
+                                                  rows=len(chunk)):
+                    feats = np.zeros((B, bucket, D), np.float32)
+                    mask = np.zeros((B, bucket), np.float32)
+                    for j, i in enumerate(chunk):
+                        n = min(utts[i].shape[0], bucket)
+                        feats[j, :n] = utts[i][:n]
+                        mask[j, :n] = valids[i][:n].astype(np.float32)
+                        self.stats["real_frames"] += n
+                        self.stats["padded_frames"] += bucket - n
                 out[chunk] = self._run_batch(
                     jnp.asarray(feats), jnp.asarray(mask))[:len(chunk)]
                 self.stats["batches"] += 1
@@ -328,5 +337,5 @@ class IVectorExtractor:
         return {"ok": ok, "mode": self.mode,
                 "degradations": self.stats["degradations"],
                 "latency_s": latency, "canary_norm": norm,
-                "buckets_compiled": len(self._seen_buckets),
+                "buckets_compiled": len(self.buckets()),
                 "error": err}

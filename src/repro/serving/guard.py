@@ -72,7 +72,7 @@ class RequestResult:
     id: int
     ivector: Optional[np.ndarray]   # None when expired/preempted
     expired: bool
-    wait_s: float                   # time spent queued
+    wait_s: float                   # from submit to the result
     info: Optional[object] = None   # RequestInfo | session ChunkInfo
     kind: str = "first"
     sid: Optional[str] = None

@@ -148,6 +148,63 @@ def test_extractor_truncation_lands_on_bucket_grid():
     assert ex.stats["compiles"] == 1     # the prefix reused the jit
 
 
+def test_extractor_counts_rows_and_compiles_per_mode_and_bucket():
+    """Each request fills one row of one batch, so ``requests`` counts
+    the filled rows of the batches sent to the device; ``compiles``
+    counts the first call of each (mode, bucket) pair, so a demotion
+    that recompiles a bucket is counted; the health-check canary is not
+    traffic and leaves the row and batch counts alone."""
+    cfg = _cfg("augmented")
+    state = _toy_state("augmented")
+    ex = IVectorExtractor.from_state(
+        cfg, state, ServingConfig(max_batch=4, min_bucket=16))
+    utts = [np.asarray(jax.random.normal(jax.random.fold_in(KEY, 80 + i),
+                                         (L, 5)), np.float32)
+            for i, L in enumerate([9, 14, 16, 11, 15, 30])]
+    ex.extract(utts)            # bucket 16: a batch of 4 and one of 1
+    assert ex.stats["batches"] == 3 and ex.stats["requests"] == 6
+    assert ex.stats["compiles"] == 2            # (sparse, 16), (sparse, 32)
+    h = ex.health_check()
+    assert h["ok"] and ex.stats["requests"] == 6
+    assert ex.stats["batches"] == 3
+    ex._chaos_fail_modes = {ex.mode}
+    with pytest.warns(RuntimeWarning, match="demoting"):
+        ex.extract(utts[:1])
+    assert ex.mode == "dense"
+    assert ex.stats["compiles"] == 3            # (dense, 16) is new
+    assert ex.stats["requests"] == 7 and ex.stats["batches"] == 4
+    ex.extract(utts[:1])
+    assert ex.stats["compiles"] == 3
+
+
+def test_admission_queue_reports_time_queued_before_the_drain():
+    """``wait_s`` runs from submit to the result: the time queued before
+    the drain and the drain's own work, on the queue's clock; a request
+    shed at a drain waited until that drain began."""
+    from repro.serving.guard import AdmissionQueue
+    cfg = _cfg("augmented")
+    state = _toy_state("augmented")
+    ex = IVectorExtractor.from_state(
+        cfg, state, ServingConfig(max_batch=4, min_bucket=16))
+    now = {"t": 0.0}
+
+    def clock():
+        now["t"] += 0.5         # every reading of the clock takes 0.5 s
+        return now["t"]
+
+    q = AdmissionQueue(ex, default_timeout=100.0, clock=clock)
+    utt = np.ones((12, 5), np.float32)
+    a = q.submit(utt)           # at 0.5
+    b = q.submit(utt)           # at 1.0
+    res = q.drain()             # starts at 1.5, results at 2.0
+    assert res[a].wait_s == 1.5 and res[b].wait_s == 1.0
+    assert not res[a].expired and res[a].ivector is not None
+    c = q.submit(utt, timeout=0.1)   # at 2.5; expires before the drain
+    res = q.drain()                  # starts at 3.0
+    assert res[c].expired and res[c].ivector is None
+    assert res[c].wait_s == 0.5
+
+
 # ---------------------------------------------------------------------------
 # Satellite regressions
 # ---------------------------------------------------------------------------
