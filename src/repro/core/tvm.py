@@ -295,15 +295,19 @@ def m_step(model: TVModel, acc: EMAccum, S_tot: Optional[jax.Array],
            update_sigma: bool) -> TVModel:
     """T update (and Σ update) from accumulated statistics [Kenny 2005].
 
-    A packed accumulator ([C, P]) is unpacked here — the batched-solve
-    boundary — exactly as L unpacks at the Cholesky boundary."""
+    A packed accumulator ([C, P]) is unpacked here — the Cholesky
+    boundary — exactly as L unpacks at the posterior's. Each A_c + εI is
+    SPD (occupancy-weighted Phi_u + φ_u φ_uᵀ plus a ridge), so T_c =
+    B_c A_c⁻¹ takes the posterior's matmul-only path (DESIGN.md §12):
+    A = G Gᵀ, Gi = G⁻¹ by ``ops.tri_inverse``, T = (B Giᵀ) Gi. The two
+    factors are applied to the D-row B; A⁻¹ is never formed."""
     with jax.named_scope("ivec_mstep"):
         R = model.rank
         A = ops.unpack_symmetric(acc.A, R) if acc.A.ndim == 2 else acc.A
-        # T_c = B_c A_c^{-1}; solve A_c^T X^T = B_c^T  (A symmetric)
         A_reg = A + 1e-6 * jnp.eye(R, dtype=f32)[None]
-        T_new = jnp.linalg.solve(A_reg, acc.B.transpose(0, 2, 1)) \
-            .transpose(0, 2, 1)
+        Gi = ops.tri_inverse(jnp.linalg.cholesky(A_reg))
+        T_new = jnp.einsum("cdr,csr->cds", acc.B, Gi, precision=HI)
+        T_new = jnp.einsum("cds,cst->cdt", T_new, Gi, precision=HI)
         Sigma = model.Sigma
         if update_sigma and S_tot is not None:
             n_safe = jnp.maximum(acc.n_tot, 1e-6)[:, None, None]

@@ -15,7 +15,7 @@ import pytest
 from repro.analysis.check import (check_jaxpr, check_kernel, check_source,
                                   run_all)
 from repro.analysis.check.cli import report_json
-from repro.core import backend, ubm
+from repro.core import backend, tvm, ubm
 from repro.kernels import registry
 
 f32 = jnp.float32
@@ -431,6 +431,21 @@ class TestSpdSolves:
                           jnp.broadcast_to(jnp.eye(4, dtype=f32),
                                            (2, 4, 4)).copy())
         assert "NUM002" not in _ids(check_jaxpr(ubm.full_precisions, gmm))
+
+    @pytest.mark.parametrize("estep", ["packed", "dense"])
+    def test_no_lu_in_m_step_jaxpr(self, estep):
+        # the M-step's T solve is a Cholesky factor plus the matmul-only
+        # triangular inverse: a pivoted LU must not come back into it
+        C, D, R = 3, 4, 5
+        model = tvm.init_model(jax.random.PRNGKey(0), jnp.zeros((C, D), f32),
+                               jnp.broadcast_to(jnp.eye(D, dtype=f32),
+                                                (C, D, D)),
+                               R, "augmented")
+        acc = tvm.EMAccum.zeros(C, D, R, estep=estep)
+        S_tot = jnp.zeros((C, D, D), f32)
+        found = check_jaxpr(lambda m, a, s: tvm.m_step(m, a, s, True),
+                            model, acc, S_tot)
+        assert "NUM002" not in _ids(found, unsuppressed_only=False)
 
 
 class TestBf16Accumulation:

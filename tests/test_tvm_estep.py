@@ -376,3 +376,57 @@ def test_posterior_packed_fast_path_matches_cho_solve():
         rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(acc_p.B), np.asarray(acc_d.B),
                                rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# M-step T update: Cholesky + tri_inverse against a float64 solve
+# ---------------------------------------------------------------------------
+
+
+def _mstep_accum(key, C, D, R, prior0, estep):
+    """EMAccum built as the E-step builds it: A_c = Σ_u n_uc (Phi_u +
+    phi_u phi_uᵀ), phi_u[0] about ``prior0`` (100 in the augmented
+    formulation), and component 0 never occupied (A_0 = 0, B_0 = 0)."""
+    Utt = 3 * R
+    n = np.asarray(jax.random.uniform(key, (Utt, C), minval=0.0,
+                                      maxval=3.0), np.float64)
+    n[:, 0] = 0.0
+    phi = np.asarray(jax.random.normal(jax.random.fold_in(key, 1),
+                                       (Utt, R)), np.float64)
+    phi[:, 0] += prior0
+    M = np.asarray(jax.random.normal(jax.random.fold_in(key, 2),
+                                     (Utt, R, R)), np.float64) * 0.3
+    Phi = M @ np.swapaxes(M, 1, 2) / R + 0.05 * np.eye(R)
+    A = np.einsum("uc,urs->crs", n, Phi + phi[:, :, None] * phi[:, None])
+    f = np.asarray(jax.random.normal(jax.random.fold_in(key, 3),
+                                     (Utt, C, D)), np.float64) * n[..., None]
+    B = np.einsum("ucd,ur->cdr", f, phi)
+    A32 = jnp.asarray(A, jnp.float32)
+    if estep == "packed":
+        A32 = ops.pack_symmetric(A32)
+    acc = TV.EMAccum(A=A32, B=jnp.asarray(B, jnp.float32),
+                     h=jnp.zeros((R,)), H=jnp.zeros((R, R)),
+                     n_tot=jnp.asarray(n.sum(0), jnp.float32),
+                     n_utts=jnp.asarray(Utt, jnp.float32))
+    return acc, A, B
+
+
+@pytest.mark.parametrize("estep", ["packed", "dense"])
+@pytest.mark.parametrize("R", [12, 21])   # tri_inverse: one block; 11 + 10
+@pytest.mark.parametrize("prior0", [0.0, 100.0])   # standard, augmented
+def test_m_step_T_matches_float64_solve(estep, R, prior0):
+    """T_c = B_c (A_c + 1e-6 I)⁻¹ from the Cholesky + tri_inverse path
+    agrees with a float64 numpy solve of the same A and B, for packed and
+    dense accumulators, odd and even R, an augmented-style A (phi[0]
+    about 100) and a zero-occupancy component (T_0 = 0, finite)."""
+    C, D = 6, 5
+    model = _toy_model(k(70), C=C, D=D, R=R)
+    acc, A, B = _mstep_accum(k(71 + R), C, D, R, prior0, estep)
+    T = np.asarray(TV.m_step(model, acc, None, False).T, np.float64)
+    assert np.isfinite(T).all()
+    want = np.swapaxes(np.linalg.solve(A + 1e-6 * np.eye(R),
+                                       np.swapaxes(B, 1, 2)), 1, 2)
+    np.testing.assert_array_equal(T[0], 0.0)
+    rel = (np.linalg.norm(T[1:] - want[1:], axis=(1, 2))
+           / np.linalg.norm(want[1:], axis=(1, 2)))
+    assert rel.max() < 5e-6, rel
